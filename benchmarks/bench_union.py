@@ -7,8 +7,15 @@ automatically) — vs a Python loop over the same jitted engine. Every run
 goes through the Experiment facade (``union.run``); engines come from the
 process-wide cache, so the warm run of each mode measures steady-state
 members/sec. Each ``BENCH_union.json`` entry records its provenance (git
-commit, jax version, backend, device count). ``--quick`` is the CI smoke
-profile.
+commit, jax version, backend, device count, compile cache). ``--quick``
+is the CI smoke profile.
+
+``main`` keeps JAX's persistent compile cache (``repro.compile_cache``),
+so a profile's "cold" numbers are a compile only where the cache held
+no entry for it. ``provenance["compile_cache"]`` records the cache
+directory and how many entries it held when the process started (0:
+every cold number is a compile); entries without that block predate
+the cache and always compiled.
 
 ``--trace`` switches to the online-scheduler profile instead: the same
 (seeds × policies) grid over a synthetic Poisson trace run both ways —
@@ -61,6 +68,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
+# set by main(): the persistent compile cache's directory and its entry
+# count when this process started
+COMPILE_CACHE = None
+
 
 def bench_scenario(quick: bool):
     from repro.union.scenario import Scenario, ScenarioJob
@@ -85,7 +96,8 @@ def bench_scenario(quick: bool):
 
 
 def provenance():
-    """Record where each BENCH entry came from: commit, jax, backend."""
+    """Record where each BENCH entry came from: commit, jax, backend,
+    and the compile cache the cold numbers may have read from."""
     import jax
 
     try:
@@ -101,6 +113,7 @@ def provenance():
         backend=jax.default_backend(),
         device_count=jax.device_count(),
         python=sys.version.split()[0],
+        compile_cache=COMPILE_CACHE,
     )
 
 
@@ -534,6 +547,20 @@ def main():
                     help="failure campaign profile: healthy vs 2%%"
                     " dead-link warm wall through one shared engine")
     args = ap.parse_args()
+    members = args.members if args.members is not None else (
+        2 if args.quick else 8)
+    profiles = (args.failures, args.trace, args.experiment, args.fabric,
+                args.serve)
+    if not any(profiles):
+        enable_host_devices(members)  # before jax is imported
+    from repro.compile_cache import use_persistent_cache
+
+    global COMPILE_CACHE
+    cache_dir = use_persistent_cache()
+    COMPILE_CACHE = dict(
+        dir=cache_dir,
+        entries_at_start=(len(os.listdir(cache_dir))
+                          if os.path.isdir(cache_dir) else 0))
     if args.failures:
         bench_failures(args.quick)
         return
@@ -549,10 +576,6 @@ def main():
     if args.serve:
         bench_serve(args.quick)
         return
-    members = args.members if args.members is not None else (
-        2 if args.quick else 8)
-    enable_host_devices(members)
-
     from repro import union
 
     sc = bench_scenario(args.quick)

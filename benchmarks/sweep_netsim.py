@@ -20,7 +20,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 OUT = os.path.join(os.path.dirname(__file__), "..", "results", "netsim")
 
 
-def main():
+def main() -> int:
+    """Run every cell; returns 1 when any cell failed, after the rest."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--workload", default="workload1")
@@ -47,6 +48,7 @@ def main():
             for rt in routings:
                 combos.append((args.workload, topo, pl, rt))
 
+    failed = []
     for wl, topo, pl, rt in combos:
         tag = f"{wl}__{topo}__{pl}__{rt}__small_s0"
         if args.members > 1:
@@ -76,9 +78,14 @@ def main():
                 json.dump(rep, f, indent=1, default=float)
             print(f"{tag}: {time.time()-t0:.0f}s virtual={virtual:.0f}ms",
                   flush=True)
-        except Exception as e:
+        except Exception as e:  # the other cells still run
             print(f"{tag}: FAIL {e}", flush=True)
+            failed.append(tag)
+    if failed:
+        print(f"{len(failed)} of {len(combos)} cells failed: "
+              f"{', '.join(failed)}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

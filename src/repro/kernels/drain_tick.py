@@ -18,13 +18,16 @@ Layout:
   (links ≤ ~74k × 4 B ≈ 296 KiB for the paper's 2-D dragonfly — far
   under the ~16 MiB VMEM budget); route width K=10 is a static lane dim.
 * per-link scatters inside the kernel use the accumulate pattern
-  (`ref[...] = ref[...] + zeros.at[idx].add(v)`); Mosaic's scatter
-  support on real TPUs is the reason `interpret=True` stays the default
-  fallback off-TPU.
+  (`ref[...] = ref[...] + zeros.at[idx].add(v)`).
 
-Validated against `ref.drain_tick_ref` (the engine's jnp path is
-bit-identical math) by tests/test_drain_kernel.py in interpret mode on
-CPU.
+**The kernel does not lower for a TPU.** Under jax 0.9.0, Mosaic refuses
+those in-kernel scatters: compiling it for a v5e raises
+``NotImplementedError: Unimplemented primitive in Pallas TPU lowering
+for KernelType.TC: scatter-add`` (pinned by tests/test_tpu_compile.py).
+So the engine's drain tick is the jnp path (`ref.drain_tick_ref`) on
+every backend; the kernel is an explicit opt-in (``use_pallas=True``)
+and runs only in interpret mode, on CPU, where
+tests/test_drain_kernel.py validates it against `ref.drain_tick_ref`.
 """
 from __future__ import annotations
 

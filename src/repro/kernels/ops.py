@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``use_pallas`` selects the kernel path (interpret mode on CPU — the
-engine/model default to the pure-jnp path off-TPU and these wrappers are
-exercised by the per-kernel allclose sweeps in tests/).
+``use_pallas`` selects the kernel path (interpret mode on CPU). The
+engine uses the pure-jnp drain on every backend — Mosaic cannot lower
+the drain kernel (``kernels/drain_tick.py``) — and these wrappers are
+exercised by the per-kernel allclose sweeps in tests/.
 """
 from __future__ import annotations
 
@@ -46,8 +47,8 @@ def drain_tick(routes, bytes_rem, active, job, min_arrive, t, dt, bw_eff,
 
     See `ref.drain_tick_ref` for shapes/semantics — ``bw_eff`` is
     ``(L+1,)`` or per-member ``(B, L+1)`` (runtime fault factors). The
-    jnp path is the engine's default off-TPU: its scatters fold the
-    member index into one flat index, which is what fixes the
+    jnp path is the engine's drain on every backend: its scatters fold
+    the member index into one flat index, which is what fixes the
     vmapped-campaign regression.
     """
     if not use_pallas:

@@ -13,13 +13,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType / make_mesh(axis_types=...) only exist on newer
-    # jax; Auto is the default there, so older versions just omit it.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

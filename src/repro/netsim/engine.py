@@ -367,7 +367,7 @@ def build_engine(
     rank_slowdown: Optional[Sequence[np.ndarray]] = None,  # per job (P,) f32
     job_start_us: Optional[Sequence[float]] = None,  # per job arrival offsets
     capacity: Optional[EngineCapacity] = None,
-    use_pallas: Optional[bool] = None,
+    use_pallas: bool = False,
     probes: Optional[ProbeConfig] = None,
     hist: Optional[HistConfig] = None,
 ):
@@ -395,8 +395,10 @@ def build_engine(
     jobs[ji].start_us)`` of virtual time — dynamic co-scheduling, where a
     job lands on a network already carrying traffic.
 
-    ``use_pallas`` routes the drain tick through the Pallas kernel
-    (default: only on TPU backends; the pure-jnp fused path elsewhere).
+    The drain tick is the jnp fused path (``kernels/ref.drain_tick_ref``)
+    on every backend. ``use_pallas=True`` opts into the Pallas kernel:
+    interpret mode off-TPU (the CPU tests), Mosaic on a TPU, where jax
+    0.9.0 refuses its in-kernel scatter-add (``kernels/drain_tick.py``).
 
     ``probes`` compiles in the sim-plane observation rings
     (:mod:`repro.obs.probes`): per-level link utilization, per-app
@@ -420,9 +422,8 @@ def build_engine(
     BINS = net.latency_hist_bins
     W = net.max_windows
     R = topo.n_routers
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    # compiled Mosaic on real TPUs; interpret-mode emulation elsewhere
+    # an opted-in Pallas drain compiles Mosaic on a TPU and is emulated
+    # in interpret mode elsewhere
     kernel_interpret = jax.default_backend() != "tpu"
 
     default_table = pack_jobs(
@@ -1302,7 +1303,7 @@ def engine_cache_key(
     pool_size: Optional[int] = None,
     horizon_us: float = 500_000.0,
     capacity: EngineCapacity,
-    use_pallas: Optional[bool] = None,
+    use_pallas: bool = False,
     probes: Optional[ProbeConfig] = None,
     hist: Optional[HistConfig] = None,
 ) -> Tuple:
@@ -1342,7 +1343,7 @@ def get_engine(
     pool_size: Optional[int] = None,
     horizon_us: float = 500_000.0,
     capacity: EngineCapacity,
-    use_pallas: Optional[bool] = None,
+    use_pallas: bool = False,
     probes: Optional[ProbeConfig] = None,
     hist: Optional[HistConfig] = None,
 ) -> Engine:

@@ -531,6 +531,11 @@ class Results:
     # `hist` / `timeline` blocks.
     telemetry: Dict[str, Any] = field(default_factory=dict)
     schema_version: int = SCHEMA_VERSION
+    # run(..., keep_states=True): each scenario cell's final engine state
+    # (SimState), aligned with `scenario_cells`; None for store hits.
+    # Never serialized.
+    states: Optional[List[Any]] = field(default=None, repr=False,
+                                        compare=False)
 
     @property
     def scenario_cells(self) -> List[CellResult]:
@@ -658,8 +663,11 @@ def _run_faulted(eng, inits, cells, host):
     return [member_state(state, i) for i in range(len(cells))]
 
 
-def _exec_batched(node, exp: Experiment) -> List[CellResult]:
-    """One engine from the shared cache, one batched call per node."""
+def node_engine(node, exp: Experiment):
+    """A batched plan node's engine from the shared cache and its cells'
+    initial member states: ``(engine, inits, cold)``, ``cold`` when this
+    call built the engine. ``repro.netsim.rehearse`` compiles the same
+    engine for a described chip."""
     host = node.host
     stats0 = engine_cache_stats()
     with span("engine.cache_get", cat="engine",
@@ -684,7 +692,23 @@ def _exec_batched(node, exp: Experiment) -> List[CellResult]:
             )
             for cell in node.cells
         ]
+    return eng, inits, cold
+
+
+def _device_ids(state) -> set:
+    """Ids of the devices that hold ``state``'s shards."""
+    return {s.device.id for s in state.t.addressable_shards}
+
+
+def _exec_batched(node, exp: Experiment):
+    """One engine from the shared cache, one batched call per node.
+    Returns the node's ``(index, CellResult)`` pairs, its members' final
+    states in cell order, and the ids of the devices the engine's final
+    state sat on."""
+    host = node.host
+    eng, inits, cold = node_engine(node, exp)
     n = len(node.cells)
+    devices: set = set()
     # cells with timed fault events need the windowed driver (mask
     # surgery at event boundaries); everything else — healthy and
     # static-pattern cells alike — keeps the plain single-dispatch run,
@@ -712,6 +736,7 @@ def _exec_batched(node, exp: Experiment) -> List[CellResult]:
                         for d in range(D)
                     ])
                     final = jax.block_until_ready(eng.prun(sharded))
+                    devices |= _device_ids(final)
                     p_states = [
                         member_state(member_state(final, i // chunk),
                                      i % chunk)
@@ -720,10 +745,13 @@ def _exec_batched(node, exp: Experiment) -> List[CellResult]:
                 else:
                     final = jax.block_until_ready(
                         eng.run(stack_members(p_inits)))
+                    devices |= _device_ids(final)
                     p_states = [member_state(final, i) for i in range(np_)]
             else:
                 p_states = [jax.block_until_ready(eng.run(s))
                             for s in p_inits]
+                for st in p_states:
+                    devices |= _device_ids(st)
             for i, st in zip(plain_ix, p_states):
                 states[i] = st
         if timed_ix:
@@ -732,6 +760,7 @@ def _exec_batched(node, exp: Experiment) -> List[CellResult]:
                 [node.cells[i] for i in timed_ix], host)
             for i, st in zip(timed_ix, f_states):
                 states[i] = st
+                devices |= _device_ids(st)
     wall = time.time() - t0
 
     out = []
@@ -747,7 +776,7 @@ def _exec_batched(node, exp: Experiment) -> List[CellResult]:
             fabric=cell.scenario.topo, failure=cell.failure_name,
             report=rep,
         )))
-    return out
+    return out, states, devices
 
 
 def _trace_cell_result(cell, trace, res, study, probes, topo,
@@ -896,7 +925,8 @@ def _consult_store(store, node, exp):
     return dc_replace(node, cells=miss_cells), hits, fps
 
 
-def run(experiment, plan=None, store=None, cancel=None) -> Results:
+def run(experiment, plan=None, store=None, cancel=None,
+        keep_states: bool = False) -> Results:
     """The facade: lower ``experiment`` through the planner and execute.
 
     Accepts an :class:`Experiment` (or a prebuilt
@@ -913,7 +943,8 @@ def run(experiment, plan=None, store=None, cancel=None) -> Results:
     one-cell change executes one cell. ``cancel`` is a zero-arg callable
     polled between plan nodes; when it returns true the run raises
     :class:`RunCancelled` (cells finished so far are already persisted
-    to the store).
+    to the store). ``keep_states`` keeps each scenario cell's final
+    engine state in ``Results.states`` for inspection.
     """
     from repro.union import planner as PLN
     from repro.union.report import results_summary
@@ -935,6 +966,8 @@ def run(experiment, plan=None, store=None, cancel=None) -> Results:
         indexed: List = []
         trace_indexed: List = []
         node_kinds: Dict[str, Dict[str, float]] = {}
+        final_states: Dict[int, Any] = {}
+        devices: set = set()
         store_hits = 0
         store_misses = 0
         reg = get_registry()
@@ -966,8 +999,14 @@ def run(experiment, plan=None, store=None, cancel=None) -> Results:
             produced: List[Tuple[int, CellResult]] = []
             if exec_node.cells:
                 if node.kind == "batched":
-                    produced = _exec_batched(exec_node, plan.experiment)
+                    produced, states, node_devs = _exec_batched(
+                        exec_node, plan.experiment)
                     indexed.extend(produced)
+                    devices |= node_devs
+                    if keep_states:
+                        final_states.update(
+                            (idx, st) for (idx, _), st in zip(produced,
+                                                              states))
                 elif node.kind == "windowed":
                     produced = _exec_windowed(exec_node, plan.experiment)
                     trace_indexed.extend(produced)
@@ -1006,6 +1045,9 @@ def run(experiment, plan=None, store=None, cancel=None) -> Results:
                 builds=stats1["builds"] - stats0["builds"],
             ),
         )
+        if keep_states:
+            res.states = [final_states.get(i)
+                          for i, _ in sorted(indexed, key=lambda p: p[0])]
         res.summary = results_summary(res)
 
         # process-plane metrics: this run's contribution to the registry
@@ -1050,6 +1092,9 @@ def run(experiment, plan=None, store=None, cancel=None) -> Results:
                     wall_s=round(v["wall_s"], 4))
             for k, v in node_kinds.items()
         },
+        # ids of the devices that held the scenario cells' final engine
+        # states (a sharded ensemble spans them all)
+        devices=sorted(devices),
         probes=(
             dict(samples=plan.experiment.probes,
                  every=plan.experiment.probe_every)

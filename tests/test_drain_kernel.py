@@ -3,7 +3,8 @@
 The drain tick is the engine's per-tick hot loop (steps 2-3): link demand
 -> fair-share rate -> per-message drain -> delivery mask + per-link byte
 counters, with an explicit member batch dim. The reference path is what
-the engine runs off-TPU; the Pallas kernel must agree bit-for-bit in
+the engine runs on every backend; the Pallas kernel, which Mosaic cannot
+lower yet (tests/test_tpu_compile.py), must agree bit-for-bit in
 interpret mode.
 """
 import jax

@@ -123,6 +123,23 @@ def test_ragged_campaign_matches_golden(golden):
         assert_member_matches(cell.report, g)
 
 
+def test_keep_states_returns_each_cells_final_state(golden):
+    """keep_states: the final engine states union.run itself produced,
+    in scenario-cell order, never in the artifact; telemetry names the
+    device that held them."""
+    exp = union.Experiment(
+        name="rag", scenarios=[sc_a(), sc_b()], members=1, seeds=[0, 1])
+    res = union.run(exp, keep_states=True)
+    assert len(res.states) == len(res.scenario_cells) == 2
+    for cell, st, g in zip(res.cells, res.states, golden["ragged"]["members"]):
+        assert_member_matches(cell.report, g)
+        assert float(st.t) / 1000.0 == cell.report["virtual_time_ms"]
+        assert int(st.pool.dropped) == cell.report["dropped"]
+    assert res.telemetry["devices"] == [0]
+    assert "states" not in res.to_dict()
+    assert union.run(exp).states is None
+
+
 def test_trace_study_matches_golden(golden):
     """--trace mode: a TraceStudy through union.run == the old
     sched.run_trace for both queue policies, per-job bit-identical."""
