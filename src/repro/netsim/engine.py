@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import math
 import os
+import re
 import warnings
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -134,6 +135,16 @@ class Metrics(NamedTuple):
     router_wins: jnp.ndarray  # (W, n_apps, R) f32 snapshots
     win_idx: jnp.ndarray
     peak_inject: jnp.ndarray  # f32 max bytes injected in one (tick, app)
+    # always-on tick counters, each gated by the member freeze mask so a
+    # member counts what its own B=1 run would (docs/obs.md):
+    ticks: jnp.ndarray  # int32 live ticks taken
+    inject_ticks: jnp.ndarray  # int32 live ticks with a candidate or UR fire
+    # f32 sum over live ticks of the pool slots active in the drain pass;
+    # exact while the total is below 2**24, then each tick's add rounds to
+    # float32 (relative error at most 2**-24 per tick; int32 would
+    # overflow at 65,536 slots x 32,768 ticks)
+    active_slot_ticks: jnp.ndarray
+    peak_active: jnp.ndarray  # int32 most pool slots active at once
 
 
 class SimState(NamedTuple):
@@ -224,6 +235,7 @@ class Engine:
     run_window: Callable
     capacity: EngineCapacity
     _prun: Optional[Callable] = None
+    _lower: Optional[Callable] = None
 
     def __iter__(self):
         return iter((self.init_state, self.run, self.tick))
@@ -236,6 +248,15 @@ class Engine:
         if self._prun is None:
             self._prun = jax.pmap(self.run)
         return self._prun
+
+    def phase_map(self, state, t_stop=None) -> Dict[str, str]:
+        """``{HLO instruction name: tick phase}`` of the run program
+        compiled for the shapes of ``state`` (``run_window``'s when
+        ``t_stop`` is given). Compiling again hits the compile cache.
+        A profile's device ops carry these instruction names, so the map
+        labels each op with the ``tick/<phase>`` scope it was traced in
+        (docs/obs.md)."""
+        return hlo_phases(self._lower(state, t_stop).compile().as_text())
 
 
 def _ceil_log2(P: int) -> int:
@@ -339,6 +360,93 @@ def _flat_min(target, idx, vals):
 def _flat_max(target, idx, vals):
     flat, gidx, shape = _flat(target, idx)
     return flat.at[gidx].max(vals.reshape(-1), mode="drop").reshape(shape)
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(([^)]*)\)")
+_HLO_SUBCOMPUTATION = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_HLO_OPERAND = re.compile(r"%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_TICK_PHASE = re.compile(r"(?:^|/)tick/(\w+)")
+# instructions that hold no device time of their own
+_HLO_STRUCTURAL = {"parameter", "constant", "get-tuple-element", "tuple",
+                   "while", "conditional"}
+
+
+def hlo_phases(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: phase}`` of a compiled module's text.
+
+    An instruction's phase is the ``tick/<phase>`` scope of its
+    ``op_name`` metadata. A fusion takes its root instruction's scope;
+    where the compiler left the root without metadata (index arithmetic
+    it added), the fusion's own, else that of the scoped instruction
+    nearest the root. Data movement the compiler inserted with no
+    metadata (copies, prefetches, layout changes outside any fusion)
+    takes the phase of the first instruction it feeds, else of the one
+    that feeds it.
+    """
+    own: Dict[str, Optional[str]] = {}
+    calls: Dict[str, str] = {}
+    roots: Dict[str, str] = {}
+    last: Dict[str, str] = {}  # computation -> its last scoped phase
+    comp_of: Dict[str, str] = {}
+    opcode: Dict[str, str] = {}
+    operands: Dict[str, list] = {}
+    bare = []  # instructions with no op_name: the compiler added them
+    inner = set()  # computations called by fusions and reductions
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        name = m.group(2)
+        comp_of[name], opcode[name] = comp, m.group(3)
+        operands[name] = _HLO_OPERAND.findall(m.group(4))
+        if m.group(1):
+            roots[comp] = name
+        op = _HLO_OP_NAME.search(line)
+        if op is None:
+            bare.append(name)
+        ph = _TICK_PHASE.search(op.group(1)) if op else None
+        own[name] = ph.group(1) if ph else None
+        if ph is not None:
+            last[comp] = ph.group(1)
+        for sub in _HLO_SUBCOMPUTATION.findall(line):
+            inner.add(sub)
+            if m.group(3) == "fusion":
+                calls[name] = sub
+
+    def phase(name, depth=0):
+        callee = calls.get(name)
+        if callee in roots and depth < 8:
+            return (phase(roots[callee], depth + 1) or own.get(name)
+                    or last.get(callee))
+        return own.get(name)
+
+    out = {n: p for n in own if (p := phase(n)) is not None}
+    users: Dict[str, list] = {}
+    for n, ops in operands.items():
+        for o in ops:
+            users.setdefault(o, []).append(n)
+    loose = [n for n in bare if n not in out and comp_of[n] not in inner
+             and opcode[n] not in _HLO_STRUCTURAL]
+    for near in (users, operands):  # what it feeds first, then its source
+        while loose:
+            left = []
+            for n in loose:
+                got = [x for x in near.get(n, ()) if x in out]
+                if got:
+                    out[n] = out[got[0]]
+                else:
+                    left.append(n)
+            if len(left) == len(loose):
+                break
+            loose = left
+    return out
 
 
 def _member_batched(fn):
@@ -723,73 +831,84 @@ def build_engine(
         # ``stop_m`` (B,) freezes members that reached their window event
         # (run_window): a stopped member must not tick past its arrival /
         # completion boundary while batch-mates are still advancing.
+        #
+        # Each phase runs under ``jax.named_scope("tick/<phase>")``; the
+        # scopes change HLO metadata only, and ``Engine.phase_map`` reads
+        # them back to name the compiled program's instructions.
         jt = state.jobs
         t = state.t  # (B,)
         B = t.shape[0]
         pool, metrics, rng = state.pool, state.metrics, state.rng
-        # per-member freeze mask: finished / horizon-capped members must not
-        # mutate (bit-identity with their own B=1 run). The mask is threaded
-        # through every write instead of double-buffering the whole state —
-        # a full-state select per tick is what made batching memory-bound.
-        live_m = (t < horizon_us) & ~(
-            jnp.all(state.vms.done, axis=(1, 2))
-            & ~jnp.any(pool.active, axis=1)
-        )
-        if stop_m is not None:
-            live_m = live_m & ~stop_m
+        with jax.named_scope("tick/loop"):
+            # per-member freeze mask: finished / horizon-capped members
+            # must not mutate (bit-identity with their own B=1 run). The
+            # mask is threaded through every write instead of
+            # double-buffering the whole state — a full-state select per
+            # tick is what made batching memory-bound.
+            live_m = (t < horizon_us) & ~(
+                jnp.all(state.vms.done, axis=(1, 2))
+                & ~jnp.any(pool.active, axis=1)
+            )
+            if stop_m is not None:
+                live_m = live_m & ~stop_m
 
         # --- 0. runtime fault mask -> effective per-link bandwidth ---
         # (B, L): the member's link factors times both endpoint routers'
         # health factors. Healthy members multiply by exact 1.0, so their
         # trajectories stay bit-identical to a fault-free engine.
-        flt = state.faults
-        rf = flt.router_factor  # (B, R)
-        eff_f = (
-            flt.link_bw_factor
-            * rf[:, link_srcr_l] * rf[:, link_dstr_l]
-        )  # (B, L)
-        bw_run = jnp.concatenate(
-            [bw_base[None, :] * eff_f,
-             jnp.ones((B, 1), jnp.float32)], axis=1,
-        )  # (B, L+1) with the dummy row
+        with jax.named_scope("tick/faults"):
+            flt = state.faults
+            rf = flt.router_factor  # (B, R)
+            eff_f = (
+                flt.link_bw_factor
+                * rf[:, link_srcr_l] * rf[:, link_dstr_l]
+            )  # (B, L)
+            bw_run = jnp.concatenate(
+                [bw_base[None, :] * eff_f,
+                 jnp.ones((B, 1), jnp.float32)], axis=1,
+            )  # (B, L+1) with the dummy row
 
         # --- 1. VM entry + emission + injection (one stacked pass) ---
-        vms, dst, sizes = vm_emit(jt, state.vms, t, live_m)
-        fired = jnp.any(dst >= 0, axis=(2, 3))  # (B, J)
+        with jax.named_scope("tick/emit"):
+            vms, dst, sizes = vm_emit(jt, state.vms, t, live_m)
+            fired = jnp.any(dst >= 0, axis=(2, 3))  # (B, J)
 
-        # per-job rng offsets reproduce the per-job-loop draw schedule:
-        # each *fired* job advanced the stream by its P*MAXE candidates.
-        adv = (
-            (jt.P * MAXE).astype(jnp.uint32) * fired.astype(jnp.uint32)
-        )  # (B, J)
-        base = rng[:, None] + jnp.cumsum(adv, axis=1) - adv  # exclusive
-        rng_jobs = rng + jnp.sum(adv, axis=1)
+            # per-job rng offsets reproduce the per-job-loop draw
+            # schedule: each *fired* job advanced the stream by its
+            # P*MAXE candidates.
+            adv = (
+                (jt.P * MAXE).astype(jnp.uint32) * fired.astype(jnp.uint32)
+            )  # (B, J)
+            base = rng[:, None] + jnp.cumsum(adv, axis=1) - adv  # exclusive
+            rng_jobs = rng + jnp.sum(adv, axis=1)
 
-        dst_f = dst.reshape(B, N)
-        sizes_f = jnp.broadcast_to(
-            sizes[:, :, :, None], (B, J, Pmax, MAXE)
-        ).reshape(B, N)
-        r2n_f = jt.r2n.reshape(B, J * Pmax)
-        srcs_node = r2n_f[:, cand_job_j * Pmax + cand_rank_j]
-        dst_node_idx = cand_job_j[None, :] * Pmax + jnp.maximum(dst_f, 0)
-        dsts_node = jnp.take_along_axis(r2n_f, dst_node_idx, axis=1)
-        rand = _hash(base[:, cand_job_j] + cand_local_j[None, :])
+            dst_f = dst.reshape(B, N)
+            sizes_f = jnp.broadcast_to(
+                sizes[:, :, :, None], (B, J, Pmax, MAXE)
+            ).reshape(B, N)
+            r2n_f = jt.r2n.reshape(B, J * Pmax)
+            srcs_node = r2n_f[:, cand_job_j * Pmax + cand_rank_j]
+            dst_node_idx = cand_job_j[None, :] * Pmax + jnp.maximum(dst_f, 0)
+            dsts_node = jnp.take_along_axis(r2n_f, dst_node_idx, axis=1)
+            rand = _hash(base[:, cand_job_j] + cand_local_j[None, :])
 
-        ur_state = state.ur
-        rng2 = rng_jobs
-        any_inject = jnp.any(fired)
-        if ur_state is not None:
-            fire = (t[:, None] >= ur_state.next_t) & live_m[:, None]  # (B,Pu)
-            rnd = _hash(
-                ur_state.count.astype(jnp.uint32) * jnp.uint32(9781)
-                + jnp.arange(Pu, dtype=jnp.uint32)[None, :]
-                + rng_jobs[:, None]
-            )
-            dstn = (rnd % jnp.uint32(n_nodes)).astype(jnp.int32)
-            ur_rand = _hash(
-                rng_jobs[:, None] + jnp.arange(Pu, dtype=jnp.uint32)[None, :]
-            )
-            any_inject = any_inject | jnp.any(fire)
+            ur_state = state.ur
+            rng2 = rng_jobs
+            injecting = jnp.any(fired, axis=1)  # (B,)
+            if ur_state is not None:
+                fire = (t[:, None] >= ur_state.next_t) & live_m[:, None]
+                rnd = _hash(
+                    ur_state.count.astype(jnp.uint32) * jnp.uint32(9781)
+                    + jnp.arange(Pu, dtype=jnp.uint32)[None, :]
+                    + rng_jobs[:, None]
+                )
+                dstn = (rnd % jnp.uint32(n_nodes)).astype(jnp.int32)
+                ur_rand = _hash(
+                    rng_jobs[:, None]
+                    + jnp.arange(Pu, dtype=jnp.uint32)[None, :]
+                )
+                injecting = injecting | jnp.any(fire, axis=1)
+            any_inject = jnp.any(injecting)
 
         # injection hides behind one real cond over the whole batch:
         # pure-drain ticks (the majority) skip the demand scatter AND all
@@ -833,145 +952,163 @@ def build_engine(
                 )
             return pool, metrics
 
-        pool, metrics = jax.lax.cond(
-            any_inject, do_inject, lambda a: a, (pool, metrics)
-        )
+        with jax.named_scope("tick/inject"):
+            pool, metrics = jax.lax.cond(
+                any_inject, do_inject, lambda a: a, (pool, metrics)
+            )
+        drained_active = pool.active  # what the drain pass sees
 
         if ur_state is not None:
-            rng2 = rng_jobs + jnp.uint32(Pu) * jnp.any(fire, axis=1).astype(
-                jnp.uint32
-            )
-            ur_state = URState(
-                next_t=jnp.where(
-                    fire, ur_state.next_t + ur.interval_us, ur_state.next_t
-                ),
-                count=ur_state.count + fire.astype(jnp.int32),
-            )
+            with jax.named_scope("tick/emit"):
+                rng2 = rng_jobs + jnp.uint32(Pu) * jnp.any(
+                    fire, axis=1).astype(jnp.uint32)
+                ur_state = URState(
+                    next_t=jnp.where(
+                        fire, ur_state.next_t + ur.interval_us,
+                        ur_state.next_t
+                    ),
+                    count=ur_state.count + fire.astype(jnp.int32),
+                )
 
         # --- 2-3. fused drain tick: demand -> fair share -> drain ->
         # delivery, plus per-link byte counters (kernels/drain_tick.py) ---
-        new_rem, _rate, delivered, lb_delta, rw_delta = KOPS.drain_tick(
-            pool.routes, pool.bytes_rem, pool.active, pool.job,
-            pool.min_arrive, t, jnp.float32(dt), bw_run, link_dstr,
-            n_apps=n_apps, n_routers=R, use_pallas=use_pallas,
-            interpret=kernel_interpret,
-        )
-        # horizon-frozen members may still carry in-flight messages: their
-        # drain results are discarded (the freeze in place of a state select)
-        new_rem = jnp.where(live_m[:, None], new_rem, pool.bytes_rem)
-        delivered = delivered & live_m[:, None]
-        link_bytes = metrics.link_bytes + lb_delta * live_m[:, None]
-        router_win = metrics.router_win + rw_delta * live_m[:, None, None]
+        with jax.named_scope("tick/drain"):
+            new_rem, _rate, delivered, lb_delta, rw_delta = KOPS.drain_tick(
+                pool.routes, pool.bytes_rem, pool.active, pool.job,
+                pool.min_arrive, t, jnp.float32(dt), bw_run, link_dstr,
+                n_apps=n_apps, n_routers=R, use_pallas=use_pallas,
+                interpret=kernel_interpret,
+            )
+            # horizon-frozen members may still carry in-flight messages:
+            # their drain results are discarded (the freeze in place of a
+            # state select)
+            new_rem = jnp.where(live_m[:, None], new_rem, pool.bytes_rem)
+            delivered = delivered & live_m[:, None]
+            link_bytes = metrics.link_bytes + lb_delta * live_m[:, None]
+            router_win = metrics.router_win + rw_delta * live_m[:, None, None]
 
         # --- latency metrics ---
-        lat = (t[:, None] + dt) - pool.inject_t  # delivered at end of tick
-        ratio = math.log(net.latency_hist_ratio)
-        bins = jnp.clip(
-            (jnp.log(jnp.maximum(lat / net.latency_hist_lo_us, 1e-6)) / ratio),
-            0, BINS - 1,
-        ).astype(jnp.int32)
-        app_of = pool.job
-        d32 = delivered.astype(jnp.int32)
-        lat_hist = _flat_add(
-            metrics.lat_hist,
-            jnp.where(delivered, app_of, 0) * BINS + jnp.where(delivered, bins, 0),
-            d32,
-        )
-        lat_sum = _flat_add(metrics.lat_sum, app_of, jnp.where(delivered, lat, 0.0))
-        lat_cnt = _flat_add(metrics.lat_cnt, app_of, d32)
-        lat_min = _flat_min(metrics.lat_min, app_of, jnp.where(delivered, lat, jnp.inf))
-        lat_max = _flat_max(metrics.lat_max, app_of, jnp.where(delivered, lat, -jnp.inf))
-
-        # full-fidelity (app, link-level) histograms (compiled in only
-        # when requested; ``delivered`` is already live_m-gated above)
-        hist_st = state.hist
-        if hist is not None:
-            msg_lvl = jnp.max(
-                jnp.where(
-                    pool.routes >= 0,
-                    hist_link_level[jnp.clip(pool.routes, 0, L)], 0,
-                ),
-                axis=-1,
-            )  # (B, M)
-            hist_st = update_hist(
-                hist_st, hist,
-                lat=lat, delivered=delivered, app=app_of, level=msg_lvl,
+        with jax.named_scope("tick/latency"):
+            lat = (t[:, None] + dt) - pool.inject_t  # delivered at tick end
+            ratio = math.log(net.latency_hist_ratio)
+            bins = jnp.clip(
+                (jnp.log(jnp.maximum(lat / net.latency_hist_lo_us, 1e-6))
+                 / ratio),
+                0, BINS - 1,
+            ).astype(jnp.int32)
+            app_of = pool.job
+            d32 = delivered.astype(jnp.int32)
+            lat_hist = _flat_add(
+                metrics.lat_hist,
+                jnp.where(delivered, app_of, 0) * BINS
+                + jnp.where(delivered, bins, 0),
+                d32,
             )
+            lat_sum = _flat_add(
+                metrics.lat_sum, app_of, jnp.where(delivered, lat, 0.0))
+            lat_cnt = _flat_add(metrics.lat_cnt, app_of, d32)
+            lat_min = _flat_min(
+                metrics.lat_min, app_of, jnp.where(delivered, lat, jnp.inf))
+            lat_max = _flat_max(
+                metrics.lat_max, app_of, jnp.where(delivered, lat, -jnp.inf))
+
+            # full-fidelity (app, link-level) histograms (compiled in only
+            # when requested; ``delivered`` is already live_m-gated above)
+            hist_st = state.hist
+            if hist is not None:
+                msg_lvl = jnp.max(
+                    jnp.where(
+                        pool.routes >= 0,
+                        hist_link_level[jnp.clip(pool.routes, 0, L)], 0,
+                    ),
+                    axis=-1,
+                )  # (B, M)
+                hist_st = update_hist(
+                    hist_st, hist,
+                    lat=lat, delivered=delivered, app=app_of, level=msg_lvl,
+                )
 
         # --- 4. delivery notifications -> VMs (UR id J is dropped) ---
-        notify = delivered & (pool.job < J)
-        sd = _flat_add(
-            vms.send_done, pool.job * Pmax + pool.src_rank,
-            notify.astype(jnp.int32), valid=notify,
-        )
-        rd = _flat_add(
-            vms.recv_done, pool.job * Pmax + pool.dst_rank,
-            notify.astype(jnp.int32), valid=notify,
-        )
-        vms = vms._replace(send_done=sd, recv_done=rd)
+        with jax.named_scope("tick/deliver"):
+            notify = delivered & (pool.job < J)
+            sd = _flat_add(
+                vms.send_done, pool.job * Pmax + pool.src_rank,
+                notify.astype(jnp.int32), valid=notify,
+            )
+            rd = _flat_add(
+                vms.recv_done, pool.job * Pmax + pool.dst_rank,
+                notify.astype(jnp.int32), valid=notify,
+            )
+            vms = vms._replace(send_done=sd, recv_done=rd)
 
-        # free delivered slots
-        freed = delivered
-        kf = jnp.cumsum(freed.astype(jnp.int32), axis=1) - 1
-        pos = pool.free_top[:, None] + kf
-        free_stack = _flat_set(
-            pool.free_stack, pos,
-            jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32), (B, M)),
-            valid=freed,
-        )
-        pool = pool._replace(
-            active=pool.active & ~delivered,
-            bytes_rem=new_rem,
-            free_stack=free_stack,
-            free_top=pool.free_top + freed.sum(axis=1),
-        )
+            # free delivered slots
+            freed = delivered
+            kf = jnp.cumsum(freed.astype(jnp.int32), axis=1) - 1
+            pos = pool.free_top[:, None] + kf
+            free_stack = _flat_set(
+                pool.free_stack, pos,
+                jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32), (B, M)),
+                valid=freed,
+            )
+            pool = pool._replace(
+                active=pool.active & ~delivered,
+                bytes_rem=new_rem,
+                free_stack=free_stack,
+                free_top=pool.free_top + freed.sum(axis=1),
+            )
 
         # --- 5. VM completion / advance (one stacked pass) ---
-        row = jnp.take_along_axis(jt.ops, vms.pc[:, :, :, None], axis=2)
-        opc, a0, a1 = row[..., 0], row[..., 1], row[..., 2]
-        P = jt.P[:, :, None]
-        nr = _n_rounds(opc, a0, a1, P, jt.logp[:, :, None])
-        tdt = t[:, None, None] + dt
-        ready = vms.emitted & ~vms.done & (tdt >= vms.busy_until)
-        sat = (vms.send_done >= vms.send_need) & (vms.recv_done >= vms.recv_need)
-        # IP2P / LOG / RESET never block; COMPUTE blocks on busy only
-        nonblock = (
-            (opc == OP["IP2P"]) | (opc == OP["LOG"]) | (opc == OP["RESET"])
-            | (opc == OP["COMPUTE"])
-        )
-        complete = ready & (sat | nonblock) & live_m[:, None, None]
-        is_comm = ~(
-            (opc == OP["COMPUTE"]) | (opc == OP["LOG"]) | (opc == OP["RESET"])
-            | (opc == OP["END"])
-        )
-        blocked = (
-            vms.emitted & ~vms.done & ~complete & (tdt >= vms.busy_until)
-            & is_comm & live_m[:, None, None]
-        )
-        comm_time = vms.comm_time + jnp.where(blocked, dt, 0.0)
+        with jax.named_scope("tick/advance"):
+            row = jnp.take_along_axis(jt.ops, vms.pc[:, :, :, None], axis=2)
+            opc, a0, a1 = row[..., 0], row[..., 1], row[..., 2]
+            P = jt.P[:, :, None]
+            nr = _n_rounds(opc, a0, a1, P, jt.logp[:, :, None])
+            tdt = t[:, None, None] + dt
+            ready = vms.emitted & ~vms.done & (tdt >= vms.busy_until)
+            sat = ((vms.send_done >= vms.send_need)
+                   & (vms.recv_done >= vms.recv_need))
+            # IP2P / LOG / RESET never block; COMPUTE blocks on busy only
+            nonblock = (
+                (opc == OP["IP2P"]) | (opc == OP["LOG"])
+                | (opc == OP["RESET"]) | (opc == OP["COMPUTE"])
+            )
+            complete = ready & (sat | nonblock) & live_m[:, None, None]
+            is_comm = ~(
+                (opc == OP["COMPUTE"]) | (opc == OP["LOG"])
+                | (opc == OP["RESET"]) | (opc == OP["END"])
+            )
+            blocked = (
+                vms.emitted & ~vms.done & ~complete & (tdt >= vms.busy_until)
+                & is_comm & live_m[:, None, None]
+            )
+            comm_time = vms.comm_time + jnp.where(blocked, dt, 0.0)
 
-        rnd2 = jnp.where(complete, vms.rnd + 1, vms.rnd)
-        advance = complete & (rnd2 >= nr)
-        pc2 = jnp.where(advance, vms.pc + 1, vms.pc)
-        rnd2 = jnp.where(advance, 0, rnd2)
-        emitted2 = vms.emitted & ~complete
-        opc_next = jnp.take_along_axis(jt.ops, pc2[:, :, :, None], axis=2)[..., 0]
-        done2 = vms.done | (opc_next == OP["END"])
-        vms = vms._replace(
-            pc=pc2, rnd=rnd2, emitted=emitted2, done=done2, comm_time=comm_time
-        )
+            rnd2 = jnp.where(complete, vms.rnd + 1, vms.rnd)
+            advance = complete & (rnd2 >= nr)
+            pc2 = jnp.where(advance, vms.pc + 1, vms.pc)
+            rnd2 = jnp.where(advance, 0, rnd2)
+            emitted2 = vms.emitted & ~complete
+            opc_next = jnp.take_along_axis(
+                jt.ops, pc2[:, :, :, None], axis=2)[..., 0]
+            done2 = vms.done | (opc_next == OP["END"])
+            vms = vms._replace(
+                pc=pc2, rnd=rnd2, emitted=emitted2, done=done2,
+                comm_time=comm_time
+            )
 
         # --- 6. window rotation (per member) ---
-        win_t = jnp.floor((t + dt) / net.window_us).astype(jnp.int32)
-        rotate = (win_t > metrics.win_idx) & live_m  # (B,)
-        wi = jnp.minimum(metrics.win_idx, W - 1)
-        wins_flat = metrics.router_wins.reshape(B * W, n_apps, R)
-        wrow = jnp.where(rotate, wi + jnp.arange(B, dtype=jnp.int32) * W, B * W)
-        router_wins = wins_flat.at[wrow].set(
-            router_win, mode="drop"
-        ).reshape(metrics.router_wins.shape)
-        router_win = jnp.where(rotate[:, None, None], 0.0, router_win)
-        win_idx = metrics.win_idx + rotate.astype(jnp.int32)
+        with jax.named_scope("tick/window"):
+            win_t = jnp.floor((t + dt) / net.window_us).astype(jnp.int32)
+            rotate = (win_t > metrics.win_idx) & live_m  # (B,)
+            wi = jnp.minimum(metrics.win_idx, W - 1)
+            wins_flat = metrics.router_wins.reshape(B * W, n_apps, R)
+            wrow = jnp.where(
+                rotate, wi + jnp.arange(B, dtype=jnp.int32) * W, B * W)
+            router_wins = wins_flat.at[wrow].set(
+                router_win, mode="drop"
+            ).reshape(metrics.router_wins.shape)
+            router_win = jnp.where(rotate[:, None, None], 0.0, router_win)
+            win_idx = metrics.win_idx + rotate.astype(jnp.int32)
 
         metrics = metrics._replace(
             lat_hist=lat_hist, lat_sum=lat_sum, lat_cnt=lat_cnt,
@@ -984,58 +1121,76 @@ def build_engine(
         # empty and every live rank is inside a COMPUTE delay (or its job
         # has not arrived yet), jump to the earliest wake-up (clamped to
         # the next metrics window).
-        any_active = jnp.any(pool.active, axis=1)  # (B,)
-        started = t[:, None] >= jt.start  # (B, J)
-        live_r = ~vms.done
-        can_act = jnp.any(
-            started[:, :, None] & live_r & ~vms.emitted, axis=(1, 2)
-        ) | jnp.any(live_r & vms.emitted & (vms.busy_until <= tdt), axis=(1, 2))
-        waiting_busy = live_r & vms.emitted & (vms.busy_until > tdt)
-        min_busy = jnp.min(
-            jnp.where(waiting_busy, vms.busy_until, jnp.inf), axis=(1, 2)
-        )
-        # a job still pending arrival wakes the sim at its start time
-        pend = ~started & jnp.any(live_r, axis=2)
-        min_busy = jnp.minimum(
-            min_busy, jnp.min(jnp.where(pend, jt.start, jnp.inf), axis=1)
-        )
-        # windowed runs: the window cap is a wake-up too (a job about to be
-        # admitted there); inert at the default t_cap=inf
-        min_busy = jnp.minimum(min_busy, jnp.asarray(t_cap, jnp.float32))
-        if ur_state is not None:
-            min_busy = jnp.minimum(min_busy, jnp.min(ur_state.next_t, axis=1))
-        next_window = (win_idx.astype(jnp.float32) + 1.0) * net.window_us
-        skip_to = jnp.minimum(min_busy, next_window)
-        idle = ~any_active & ~can_act & jnp.isfinite(skip_to)
-        # windowed runs only (t_cap finite): a member whose last job just
-        # completed must not jump ahead — the scheduler reads its ``t`` as
-        # "now" when starting queued jobs on the freed nodes. Inert at
-        # t_cap=inf: such a member's run loop exits before the next tick,
-        # so the jump was never observable.
-        all_done_m = jnp.all(vms.done, axis=(1, 2)) & ~any_active
-        idle = idle & ~(
-            all_done_m & jnp.isfinite(jnp.asarray(t_cap, jnp.float32))
-        )
-        t_new = jnp.where(idle, jnp.maximum(t + dt, skip_to), t + dt)
-        t_out = jnp.where(live_m, t_new, t)
+        with jax.named_scope("tick/skip"):
+            any_active = jnp.any(pool.active, axis=1)  # (B,)
+            started = t[:, None] >= jt.start  # (B, J)
+            live_r = ~vms.done
+            can_act = jnp.any(
+                started[:, :, None] & live_r & ~vms.emitted, axis=(1, 2)
+            ) | jnp.any(
+                live_r & vms.emitted & (vms.busy_until <= tdt), axis=(1, 2))
+            waiting_busy = live_r & vms.emitted & (vms.busy_until > tdt)
+            min_busy = jnp.min(
+                jnp.where(waiting_busy, vms.busy_until, jnp.inf), axis=(1, 2)
+            )
+            # a job still pending arrival wakes the sim at its start time
+            pend = ~started & jnp.any(live_r, axis=2)
+            min_busy = jnp.minimum(
+                min_busy, jnp.min(jnp.where(pend, jt.start, jnp.inf), axis=1)
+            )
+            # windowed runs: the window cap is a wake-up too (a job about
+            # to be admitted there); inert at the default t_cap=inf
+            min_busy = jnp.minimum(min_busy, jnp.asarray(t_cap, jnp.float32))
+            if ur_state is not None:
+                min_busy = jnp.minimum(
+                    min_busy, jnp.min(ur_state.next_t, axis=1))
+            next_window = (win_idx.astype(jnp.float32) + 1.0) * net.window_us
+            skip_to = jnp.minimum(min_busy, next_window)
+            idle = ~any_active & ~can_act & jnp.isfinite(skip_to)
+            # windowed runs only (t_cap finite): a member whose last job
+            # just completed must not jump ahead — the scheduler reads its
+            # ``t`` as "now" when starting queued jobs on the freed nodes.
+            # Inert at t_cap=inf: such a member's run loop exits before the
+            # next tick, so the jump was never observable.
+            all_done_m = jnp.all(vms.done, axis=(1, 2)) & ~any_active
+            idle = idle & ~(
+                all_done_m & jnp.isfinite(jnp.asarray(t_cap, jnp.float32))
+            )
+            t_new = jnp.where(idle, jnp.maximum(t + dt, skip_to), t + dt)
+            t_out = jnp.where(live_m, t_new, t)
+            rng_out = jnp.where(live_m, rng2 + jnp.uint32(1), rng)
 
-        # --- 8. sim-plane probes (compiled in only when requested) ---
+        # --- 8. always-on tick counters (per member, live ticks only) ---
+        with jax.named_scope("tick/counters"):
+            live_i = live_m.astype(jnp.int32)
+            n_active = jnp.sum(drained_active, axis=1, dtype=jnp.int32)
+            metrics = metrics._replace(
+                ticks=metrics.ticks + live_i,
+                inject_ticks=metrics.inject_ticks
+                + (injecting & live_m).astype(jnp.int32),
+                active_slot_ticks=metrics.active_slot_ticks
+                + (n_active * live_i).astype(jnp.float32),
+                peak_active=jnp.maximum(
+                    metrics.peak_active, n_active * live_i),
+            )
+
+        # --- 9. sim-plane probes (compiled in only when requested) ---
         probes_st = state.probes
         if probes is not None:
-            probes_st = sample_probes(
-                probes_st, probes,
-                t_new=t_out, live_m=live_m,
-                link_bytes=metrics.link_bytes,
-                pool_active=pool.active, pool_job=pool.job,
-                pool_inject_t=pool.inject_t, free_top=pool.free_top,
-                level_mask=probe_level_mask, level_bw=probe_level_bw,
-                n_apps=n_apps, pool_size=M,
-            )
+            with jax.named_scope("tick/probes"):
+                probes_st = sample_probes(
+                    probes_st, probes,
+                    t_new=t_out, live_m=live_m, ticks=metrics.ticks,
+                    link_bytes=metrics.link_bytes,
+                    pool_active=pool.active, pool_job=pool.job,
+                    pool_inject_t=pool.inject_t, free_top=pool.free_top,
+                    level_mask=probe_level_mask, level_bw=probe_level_bw,
+                    n_apps=n_apps, pool_size=M,
+                )
 
         return SimState(
             t=t_out, vms=vms, ur=ur_state, pool=pool,
-            metrics=metrics,
-            rng=jnp.where(live_m, rng2 + jnp.uint32(1), rng),
+            metrics=metrics, rng=rng_out,
             jobs=jt, ur_nodes=state.ur_nodes, probes=probes_st,
             hist=hist_st, faults=state.faults,
         )
@@ -1129,6 +1284,10 @@ def build_engine(
             router_wins=jnp.zeros((W, n_apps, R), jnp.float32),
             win_idx=jnp.int32(0),
             peak_inject=jnp.float32(0.0),
+            ticks=jnp.int32(0),
+            inject_ticks=jnp.int32(0),
+            active_slot_ticks=jnp.float32(0.0),
+            peak_active=jnp.int32(0),
         )
         if faults is None:
             flt = FaultState(
@@ -1175,11 +1334,13 @@ def build_engine(
     # tick_batched's live_m mask freezes finished members in place (no
     # whole-state double-buffer select), keeping each bit-identical to its
     # own B=1 run while stragglers tick on.
+    def any_live(s: SimState):
+        with jax.named_scope("tick/loop"):
+            return jnp.any(live(s))
+
     @jax.jit
     def run_batched(state: SimState) -> SimState:
-        return jax.lax.while_loop(
-            lambda s: jnp.any(live(s)), tick_batched, state
-        )
+        return jax.lax.while_loop(any_live, tick_batched, state)
 
     def done_slots(s: SimState):
         """(B,) count of fully-done job slots (vacant slots count too)."""
@@ -1203,10 +1364,15 @@ def build_engine(
         n0 = done_slots(state)
 
         def stopped(s):
-            return ~(live(s) & (s.t < t_stop) & (done_slots(s) <= n0))
+            with jax.named_scope("tick/loop"):
+                return ~(live(s) & (s.t < t_stop) & (done_slots(s) <= n0))
+
+        def running(s):
+            with jax.named_scope("tick/loop"):
+                return ~jnp.all(stopped(s))
 
         return jax.lax.while_loop(
-            lambda s: ~jnp.all(stopped(s)),
+            running,
             lambda s: tick_batched(s, t_stop, stop_m=stopped(s)),
             state,
         )
@@ -1221,12 +1387,24 @@ def build_engine(
 
         return wrapper
 
+    def lower(state: SimState, t_stop=None):
+        # shapes in, as a member or a batch: the run program (run_window's
+        # when ``t_stop`` is given) lowered exactly as ``run`` calls it
+        if len(state.t.shape) == 0:
+            state = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct((1,) + tuple(x.shape),
+                                               x.dtype), state)
+        if t_stop is None:
+            return run_batched.lower(state)
+        return run_window_batched.lower(state, t_stop)
+
     return Engine(
         init_state=init_state,
         run=_member_batched(run_batched),
         tick=_member_batched(tick_batched),
         run_window=_member_window(run_window_batched),
         capacity=cap,
+        _lower=lower,
     )
 
 

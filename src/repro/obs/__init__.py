@@ -26,7 +26,7 @@ See ``docs/obs.md`` for the span taxonomy and buffer/accumulator layouts.
 """
 from repro.obs.spans import (  # noqa: F401
     Tracer, get_tracer, enable, disable, tracing,
-    span, counter, summarize,
+    span, counter, summarize, note_program, device_phases,
 )
 from repro.obs.export import (  # noqa: F401
     log, get_logger, set_verbosity, log_to_jsonl,
@@ -50,7 +50,7 @@ from repro.obs.metrics import (  # noqa: F401
 
 __all__ = [
     "Tracer", "get_tracer", "enable", "disable", "tracing",
-    "span", "counter", "summarize",
+    "span", "counter", "summarize", "note_program", "device_phases",
     "log", "get_logger", "set_verbosity", "log_to_jsonl",
     "chrome_events", "write_chrome_trace", "write_jsonl",
     "ProbeConfig", "ProbeState", "init_probes", "sample_probes",

@@ -15,9 +15,10 @@ just more pytree leaves, so batching, windowed scheduler runs, and
 ``vmap`` all work unchanged.
 
 Sampling math mirrors the engine's own write discipline: every update is
-gated member-wise by ``live_m`` (frozen batch members never advance
-their tick counter or touch their buffers), and ring writes are one-hot
-``where`` selects at ``idx % K`` — no data-dependent shapes.
+gated member-wise by ``live_m`` (frozen batch members never advance the
+engine's live-tick counter ``Metrics.ticks`` or touch their buffers), and
+ring writes are one-hot ``where`` selects at ``idx % K`` — no
+data-dependent shapes.
 """
 from __future__ import annotations
 
@@ -62,7 +63,6 @@ class ProbeState(NamedTuple):
     inflight_lat: jnp.ndarray  # (K, n_apps) f32 — mean in-flight age (us)
     queue_depth: jnp.ndarray  # (K, n_apps) int32 — in-flight msgs per app
     pool_occ: jnp.ndarray     # (K,) f32 — pool slot occupancy 0..1
-    tick: jnp.ndarray         # () int32 — live ticks elapsed (ordinal clock)
     idx: jnp.ndarray          # () int32 — samples ever written (monotonic)
     last_level_bytes: jnp.ndarray  # (n_levels,) f32 — bytes at last sample
     last_t: jnp.ndarray       # () f32 — virtual time of last sample
@@ -77,7 +77,6 @@ def init_probes(cfg: ProbeConfig, n_levels: int, n_apps: int) -> ProbeState:
         inflight_lat=jnp.zeros((K, n_apps), jnp.float32),
         queue_depth=jnp.zeros((K, n_apps), jnp.int32),
         pool_occ=jnp.zeros((K,), jnp.float32),
-        tick=jnp.int32(0),
         idx=jnp.int32(0),
         last_level_bytes=jnp.zeros((n_levels,), jnp.float32),
         last_t=jnp.float32(0.0),
@@ -90,6 +89,7 @@ def sample_probes(
     *,
     t_new: jnp.ndarray,        # (B,) f32 — post-tick virtual time
     live_m: jnp.ndarray,       # (B,) bool — member freeze mask
+    ticks: jnp.ndarray,        # (B,) int32 — live ticks, this one included
     link_bytes: jnp.ndarray,   # (B, L+1) f32 — cumulative per-link bytes
     pool_active: jnp.ndarray,  # (B, M) bool
     pool_job: jnp.ndarray,     # (B, M) int32 app ids (UR == n_apps-1)
@@ -103,14 +103,13 @@ def sample_probes(
     """One tick's probe update (runs inside the jitted engine tick).
 
     Frozen members (``live_m`` false) neither advance their ordinal clock
-    nor write — a member's sample trajectory is identical whether it runs
-    solo or stacked with stragglers.
+    (the engine's ``Metrics.ticks``) nor write — a member's sample
+    trajectory is identical whether it runs solo or stacked with
+    stragglers.
     """
     K = cfg.samples
     B = t_new.shape[0]
-    live_i = live_m.astype(jnp.int32)
-    tick2 = ps.tick + live_i  # (B,)
-    do = live_m & (tick2 % cfg.every == 0)  # (B,)
+    do = live_m & (ticks % cfg.every == 0)  # (B,)
     oh = (jnp.arange(K, dtype=jnp.int32)[None, :] == (ps.idx % K)[:, None]) \
         & do[:, None]  # (B, K) one-hot ring write mask
 
@@ -147,7 +146,6 @@ def sample_probes(
         queue_depth=jnp.where(
             w2, cnt.astype(jnp.int32)[:, None, :], ps.queue_depth),
         pool_occ=jnp.where(oh, occ[:, None], ps.pool_occ),
-        tick=tick2,
         idx=ps.idx + do.astype(jnp.int32),
         last_level_bytes=jnp.where(
             do[:, None], lev_bytes, ps.last_level_bytes),

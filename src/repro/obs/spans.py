@@ -16,6 +16,13 @@ the scheduler loop). Enable it with :func:`enable` (the CLI's
 Chrome trace-event JSON (load in Perfetto / ``chrome://tracing``) or a
 structured JSONL run log.
 
+While tracing, each span also opens a ``jax.profiler.TraceAnnotation``
+of the same name, so a ``jax.profiler`` trace recorded meanwhile shows
+the span on its host plane, on the device trace's own clock. Programs
+run while tracing are noted (:func:`note_program`) so that
+:func:`device_phases` can name the device ops of such a trace by the
+phase of the engine tick they belong to.
+
 Spans are thread-safe: each thread gets its own Chrome ``tid`` row, and
 event recording takes one lock around a list append.
 """
@@ -24,7 +31,9 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class SpanHandle:
@@ -65,6 +74,9 @@ class Tracer:
         self.enabled = False
         self.origin_ns = time.perf_counter_ns()
         self.events: List[Dict[str, Any]] = []
+        # key -> (phase-map function, its arguments), and the maps made
+        self.programs: Dict[Any, tuple] = {}
+        self._phases: Dict[Any, Dict[str, str]] = {}
 
     # -- lifecycle -----------------------------------------------------
     def enable(self) -> None:
@@ -77,6 +89,8 @@ class Tracer:
         with self._lock:
             self.events = []
             self._tids = {}
+            self.programs = {}
+            self._phases = {}
             self.origin_ns = time.perf_counter_ns()
 
     @property
@@ -117,6 +131,26 @@ class Tracer:
         with self._lock:
             self.events.append(ev)
 
+    def note_program(self, key, phase_map: Callable, *args) -> None:
+        """Remember a program that ran while tracing: ``phase_map(*args)``
+        returns its ``{HLO instruction name: phase}`` map (for an engine,
+        ``Engine.phase_map`` on the shapes of the run's input)."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self.programs.setdefault(key, (phase_map, args))
+
+    def device_phases(self) -> Dict[str, str]:
+        """The merged phase maps of every program noted, each computed
+        on the first call that finds it (so call this after the timed
+        work: it compiles, or loads from the compile cache)."""
+        out: Dict[str, str] = {}
+        for key, (fn, args) in list(self.programs.items()):
+            if key not in self._phases:
+                self._phases[key] = fn(*args)
+            out.update(self._phases[key])
+        return out
+
 
 _TRACER = Tracer()
 
@@ -150,7 +184,8 @@ def span(name: str, /, cat: str = "host", **args):
     t0 = time.perf_counter_ns()
     c0 = time.process_time_ns()
     try:
-        yield handle
+        with TraceAnnotation(name):
+            yield handle
     finally:
         dur = time.perf_counter_ns() - t0
         cpu = time.process_time_ns() - c0
@@ -159,6 +194,18 @@ def span(name: str, /, cat: str = "host", **args):
 
 def counter(name: str, **values: float) -> None:
     _TRACER.counter(name, **values)
+
+
+def note_program(key, phase_map: Callable, *args) -> None:
+    _TRACER.note_program(key, phase_map, *args)
+
+
+def device_phases() -> Dict[str, str]:
+    """``{HLO instruction name: tick phase}`` of every engine program run
+    while spans were on: the names a ``jax.profiler`` trace gives its
+    device ops (``XLA Ops``), each labelled with its ``tick/<phase>``
+    scope (docs/obs.md)."""
+    return _TRACER.device_phases()
 
 
 def summarize(events: Optional[List[Dict[str, Any]]] = None,

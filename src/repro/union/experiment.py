@@ -47,6 +47,7 @@ from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
 import jax
+import numpy as np
 
 from repro.netsim.engine import (
     engine_cache_stats,
@@ -57,9 +58,11 @@ from repro.netsim.engine import (
 from repro.obs import (
     Progress,
     ProbeConfig,
+    counter,
     get_registry,
     get_tracer,
     log as obs_log,
+    note_program,
     span,
     summarize,
     tracing,
@@ -624,14 +627,45 @@ class RunCancelled(RuntimeError):
 # the executor: Plan nodes -> cells
 # ---------------------------------------------------------------------------
 
+def _engine_counts(state):
+    """Each member's ``ticks``, ``inject_ticks`` and ``active_slot_ticks``
+    (float64, flat over any leading axes)."""
+    m = jax.device_get((state.metrics.ticks, state.metrics.inject_ticks,
+                        state.metrics.active_slot_ticks))
+    return [np.asarray(x, np.float64).reshape(-1) for x in m]
+
+
+def _count_run(eng, final, *args, before=None, program=True):
+    """While spans are on, and only then: note the run's program for
+    ``repro.obs.device_phases`` (``args`` are the run's inputs after the
+    state, whose shapes ``final`` shares) and emit one
+    ``engine.counters`` event for the run that produced ``final``, less
+    the counts ``before`` it. ``iterations`` is the largest member's
+    live ticks: the while loop runs until its last member stops."""
+    if not tracing():
+        return
+    if program:
+        spec = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), np.result_type(x)),
+            (final,) + args)
+        leaves, tree = jax.tree_util.tree_flatten(spec)
+        note_program((id(eng.run), tree, tuple(leaves)),
+                     eng.phase_map, *spec)
+    ticks, inject, active = _engine_counts(final)
+    if before is not None:
+        ticks, inject, active = (a - b for a, b in zip(
+            (ticks, inject, active), before))
+    counter("engine.counters", iterations=ticks.max(),
+            member_ticks=ticks.sum(), inject_ticks=inject.sum(),
+            active_slot_ticks=active.sum())
+
+
 def _run_faulted(eng, inits, cells, host):
     """Drive timed-failure scenario cells through ``eng.run_window``,
     applying each cell's :class:`~repro.netsim.faults.FaultEvent`\\ s at
     their sim-times. One stacked batch, per-member ``t_stop`` capped at
     each member's own next event — members with no pending event run to
     the horizon while batch-mates pause for mask surgery."""
-    import numpy as np
-
     from repro.netsim.faults import set_member_faults
 
     horizon = float(host.horizon_us)
@@ -659,7 +693,9 @@ def _run_faulted(eng, inits, cells, host):
                 cur[i] += 1
             if cur[i] < len(tl):
                 t_stop[i] = tl[cur[i]][0]
+        before = _engine_counts(state) if tracing() else None
         state = jax.block_until_ready(eng.run_window(state, t_stop))
+        _count_run(eng, state, t_stop, before=before)
     return [member_state(state, i) for i in range(len(cells))]
 
 
@@ -736,6 +772,8 @@ def _exec_batched(node, exp: Experiment):
                         for d in range(D)
                     ])
                     final = jax.block_until_ready(eng.prun(sharded))
+                    # pmap compiles its own program: counted, not mapped
+                    _count_run(eng, final, program=False)
                     devices |= _device_ids(final)
                     p_states = [
                         member_state(member_state(final, i // chunk),
@@ -745,12 +783,15 @@ def _exec_batched(node, exp: Experiment):
                 else:
                     final = jax.block_until_ready(
                         eng.run(stack_members(p_inits)))
+                    _count_run(eng, final)
                     devices |= _device_ids(final)
                     p_states = [member_state(final, i) for i in range(np_)]
             else:
-                p_states = [jax.block_until_ready(eng.run(s))
-                            for s in p_inits]
-                for st in p_states:
+                p_states = []
+                for s in p_inits:
+                    st = jax.block_until_ready(eng.run(s))
+                    _count_run(eng, st)
+                    p_states.append(st)
                     devices |= _device_ids(st)
             for i, st in zip(plain_ix, p_states):
                 states[i] = st
@@ -764,18 +805,19 @@ def _exec_batched(node, exp: Experiment):
     wall = time.time() - t0
 
     out = []
-    for cell, st in zip(node.cells, states):
-        rep = MGR.member_report(
-            st, cell.rs, wall / n, seed=cell.seed, strict=exp.strict,
-            start_us=cell.start_us, capacity=node.capacity,
-        )
-        out.append((cell.index, CellResult(
-            kind="scenario", name=cell.scenario.name, seed=cell.seed,
-            placement=cell.scenario.placement,
-            routing=cell.scenario.routing, member=cell.member,
-            fabric=cell.scenario.topo, failure=cell.failure_name,
-            report=rep,
-        )))
+    with span("engine.report", cat="engine", cells=n):
+        for cell, st in zip(node.cells, states):
+            rep = MGR.member_report(
+                st, cell.rs, wall / n, seed=cell.seed, strict=exp.strict,
+                start_us=cell.start_us, capacity=node.capacity,
+            )
+            out.append((cell.index, CellResult(
+                kind="scenario", name=cell.scenario.name, seed=cell.seed,
+                placement=cell.scenario.placement,
+                routing=cell.scenario.routing, member=cell.member,
+                fabric=cell.scenario.topo, failure=cell.failure_name,
+                report=rep,
+            )))
     return out, states, devices
 
 

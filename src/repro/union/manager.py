@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import workloads as W
@@ -199,6 +200,7 @@ def bind_jobs(eng: Engine, rs: ResolvedScenario) -> Engine:
     return Engine(
         init_state=init_state, run=eng.run, tick=eng.tick,
         run_window=eng.run_window, capacity=eng.capacity, _prun=eng.prun,
+        _lower=eng._lower,
     )
 
 
@@ -225,6 +227,16 @@ def member_report(state, rs: ResolvedScenario, wall_s: float = 0.0,
             for ji in range(len(rs.jobs))
         ],
         envelope=dict(Jmax=cap.Jmax, Pmax=cap.Pmax, OPmax=cap.OPmax),
+    )
+    m = state.metrics
+    ticks, inject_ticks, active, peak = jax.device_get(
+        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.peak_active))
+    ticks, slots = int(ticks), int(state.pool.active.shape[-1])
+    rep["engine"] = dict(
+        ticks=ticks, inject_ticks=int(inject_ticks),
+        mean_pool_occupancy=(float(active) / (ticks * slots)
+                             if ticks else 0.0),
+        peak_pool_occupancy=int(peak) / slots,
     )
     if getattr(state, "probes", None) is not None:
         from repro.obs import probe_timelines

@@ -252,7 +252,8 @@ def _plan(exp) -> Plan:
     fails = exp.grid.failures or [None]
     cells: List[ScenarioCell] = []
     for v, sc in enumerate(variants):
-        rs = MGR.resolve(sc, seed=seeds[v][0] if seeds[v] else 0)
+        with span("planner.resolve", cat="planner", scenario=sc.name):
+            rs = MGR.resolve(sc, seed=seeds[v][0] if seeds[v] else 0)
         base_start = np.asarray(rs.start_us, np.float32)
         for fl in fails:
             for m, seed in enumerate(seeds[v]):

@@ -36,7 +36,8 @@ import numpy as np
 
 # Bump when engine semantics change in a way the fingerprint inputs do
 # not capture (a changed store version invalidates every prior entry).
-STORE_VERSION = 1
+# 2: member reports carry the engine's tick counters (``report["engine"]``).
+STORE_VERSION = 2
 
 _VERSIONS: Optional[Dict[str, Any]] = None
 

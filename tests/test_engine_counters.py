@@ -1,0 +1,210 @@
+"""The engine's always-on tick counters, its named tick phases, and the
+host-plane tracing that reads them.
+
+* ``Metrics.ticks`` / ``inject_ticks`` / ``active_slot_ticks`` /
+  ``peak_active`` equal a host replay that steps ``eng.tick`` one tick
+  at a time, for a batch whose members finish on different ticks;
+* ``Engine.phase_map`` names every ``tick/<phase>`` scope;
+* spans mirror onto a ``jax.profiler`` trace's host plane only while
+  tracing, and the facade emits ``engine.counters`` only then.
+"""
+import glob
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+
+from repro import obs, union
+from repro.netsim.engine import hlo_phases, stack_members
+from repro.obs import ProbeConfig
+from repro.union import manager as MGR
+
+import test_engine_equivalence as EQ
+import test_obs as OBS
+
+HORIZON_US = EQ.mixed_scenario().horizon_ms * 1000.0
+PHASES = {"loop", "faults", "emit", "inject", "drain", "latency", "deliver",
+          "advance", "window", "skip", "counters", "probes"}
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """The golden mix (staggered jobs, UR traffic) as a B=2 batch whose
+    second member's ping-pong job arrives later, so it finishes later."""
+    rs = MGR.resolve(EQ.mixed_scenario(), seed=3)
+    eng = MGR.build(rs)
+    s0 = eng.init_state(seed=MGR._engine_seed(3))
+    s1 = eng.init_state(seed=11, start_us=[0.0, 2300.0])
+    return eng, stack_members([s0, s1])
+
+
+def _replay(eng, state):
+    """Step ``eng.tick`` until every member stops; count on the host what
+    each member's counters should read."""
+    tick = jax.jit(eng.tick)
+    B = state.t.shape[0]
+    ticks = np.zeros(B, np.int64)
+    inject = np.zeros(B, np.int64)
+    active = np.zeros(B, np.float64)
+    peak = np.zeros(B, np.int64)
+    s = jax.device_get(state)
+    while True:
+        done = s.vms.done.all(axis=(1, 2)) & ~s.pool.active.any(axis=1)
+        live = (s.t < HORIZON_US) & ~done
+        if not live.any():
+            return s, ticks, inject, active, peak
+        nxt = jax.device_get(tick(s))
+        # the rng advances by 1 a live tick, plus P*MAXE for each fired
+        # job and Pu when the UR source fired
+        drng = (nxt.rng.astype(np.int64) - s.rng.astype(np.int64)) % 2**32
+        assert (drng >= 1).tolist() == live.tolist()
+        # slots the drain saw = active after the tick + delivered in it
+        delivered = nxt.metrics.lat_cnt.sum(1) - s.metrics.lat_cnt.sum(1)
+        seen = nxt.pool.active.sum(1) + delivered
+        ticks += live
+        inject += live & (drng > 1)
+        active += np.where(live, seen, 0)
+        peak = np.maximum(peak, np.where(live, seen, 0))
+        s = nxt
+
+
+def test_counters_equal_a_host_replay(mixed):
+    eng, state = mixed
+    final = jax.device_get(eng.run(state))
+    s, ticks, inject, active, peak = _replay(eng, state)
+    m = final.metrics
+    # the members stop on different ticks, and the replay ends where run does
+    assert ticks[0] != ticks[1]
+    np.testing.assert_array_equal(s.t, final.t)
+    np.testing.assert_array_equal(m.ticks, ticks)
+    np.testing.assert_array_equal(m.inject_ticks, inject)
+    np.testing.assert_array_equal(m.peak_active, peak)
+    np.testing.assert_allclose(m.active_slot_ticks, active, rtol=1e-6)
+    assert (inject > 0).all() and (inject < ticks).all()
+    assert (peak > 0).all()
+
+
+def test_report_engine_block(mixed):
+    eng, state = mixed
+    rs = MGR.resolve(EQ.mixed_scenario(), seed=3)
+    final = jax.device_get(eng.run(state))
+    st = jax.tree_util.tree_map(lambda x: x[0], final)
+    blk = MGR.member_report(st, rs)["engine"]
+    M = st.pool.active.shape[-1]
+    assert blk["ticks"] == int(st.metrics.ticks)
+    assert blk["inject_ticks"] == int(st.metrics.inject_ticks)
+    assert blk["mean_pool_occupancy"] == pytest.approx(
+        float(st.metrics.active_slot_ticks) / (blk["ticks"] * M))
+    assert 0.0 < blk["mean_pool_occupancy"] <= blk["peak_pool_occupancy"]
+    assert blk["peak_pool_occupancy"] == int(st.metrics.peak_active) / M
+
+
+def test_phase_map_names_every_phase():
+    """A probed engine with a UR source compiles every phase; each mapped
+    instruction carries a ``tick/`` scope or feeds one."""
+    rs = MGR.resolve(EQ.mixed_scenario(), seed=3)
+    eng = MGR.build(rs, probes=ProbeConfig(samples=8, every=4))
+    pm = eng.phase_map(eng.init_state(seed=1))
+    assert set(pm.values()) == PHASES
+    text = eng._lower(eng.init_state(seed=1)).compile().as_text()
+    assert hlo_phases(text) == pm
+    for line in text.splitlines():
+        m = re.match(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if m and op and m.group(1) in pm and " fusion(" not in line:
+            assert f"tick/{pm[m.group(1)]}" in op.group(1)
+
+
+def test_hlo_phases_rules():
+    text = "\n".join([
+        "%fused_computation.1 (p: f32[4]) -> f32[4] {",
+        '  %a.1 = f32[4]{0} add(%p, %p), metadata={op_name="jit(f)/while/'
+        'body/tick/drain/add"}',
+        "  ROOT %c.1 = f32[4]{0} concatenate(%a.1)",
+        "}",
+        "%body (s: f32[4]) -> f32[4] {",
+        "  %g = f32[4]{0} get-tuple-element(%s), index=0",
+        "  %cp = f32[4]{0} copy(%g)",
+        "  %fusion.7 = f32[4]{0} fusion(%cp), kind=kLoop, "
+        "calls=%fused_computation.1",
+        '  %m = f32[4]{0} multiply(%fusion.7, %fusion.7), metadata={op_name='
+        '"jit(f)/while/body/tick/emit/cond/branch_1_fun/mul"}',
+        '  ROOT %x = f32[4]{0} add(%m, %m), metadata={op_name="jit(f)/add"}',
+        "}",
+    ])
+    pm = hlo_phases(text)
+    # the fusion's root has no metadata: the scope nearest the root
+    assert pm["fusion.7"] == "drain"
+    # a copy the compiler added takes the phase of what it feeds
+    assert pm["cp"] == "drain"
+    assert pm["m"] == "emit"
+    assert "x" not in pm and "g" not in pm
+
+
+def _host_events(path):
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(path)
+    return {e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_span_on_the_profile_host_plane(tmp_path, tracer, on):
+    if on:
+        tracer.enable()
+    else:
+        tracer.disable()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("probe.mirrored", cat="test"):
+            jax.block_until_ready(jax.numpy.ones(8) + 1)
+    tracer.disable()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    assert ("probe.mirrored" in _host_events(path)) == on
+
+
+@pytest.fixture
+def tracer():
+    tr = obs.get_tracer()
+    was_enabled, events = tr.enabled, tr.events
+    programs, phases = tr.programs, tr._phases
+    tr.events, tr.programs, tr._phases = [], {}, {}
+    yield tr
+    tr.enabled, tr.events = was_enabled, events
+    tr.programs, tr._phases = programs, phases
+
+
+def _tiny_experiment(members=2, vmapped=True):
+    return union.Experiment(name="tiny-counters",
+                            scenarios=[OBS.tiny_scenario()],
+                            members=members, vmapped=vmapped)
+
+
+@pytest.mark.parametrize("vmapped", [True, False])
+def test_engine_counters_event_only_with_spans(tracer, vmapped):
+    tr = tracer
+    tr.disable()
+    union.run(_tiny_experiment(vmapped=vmapped))
+    assert not [e for e in tr.events if e["name"] == "engine.counters"]
+    assert not tr.programs
+
+    tr.enable()
+    res = union.run(_tiny_experiment(vmapped=vmapped))
+    tr.disable()
+    evs = [e["args"] for e in tr.events if e["name"] == "engine.counters"]
+    ticks = [c.report["engine"]["ticks"] for c in res.cells]
+    # one event per engine run: one batched run, or one run a member
+    assert len(evs) == (1 if vmapped else len(ticks))
+    assert sum(e["member_ticks"] for e in evs) == sum(ticks)
+    assert sum(e["iterations"] for e in evs) == (
+        max(ticks) if vmapped else sum(ticks))
+    assert all(e["active_slot_ticks"] > 0 for e in evs)
+    names = {e["name"] for e in tr.events}
+    assert {"planner.resolve", "engine.report"} <= names
+    pm = obs.device_phases()
+    assert pm and set(pm.values()) <= PHASES
+    assert len(tr.programs) == 1  # one program for every run of the shape

@@ -113,7 +113,7 @@ def test_probe_sampling_cadence_and_values():
     assert all(0.0 <= v <= 1.0 for v in tl["pool_occupancy"])
     assert all(d >= 0 for vs in tl["queue_depth"].values() for d in vs)
     # tick counter counted live ticks only; idx = ticks // every
-    assert int(st.probes.idx) == int(st.probes.tick) // 2
+    assert int(st.probes.idx) == int(st.metrics.ticks) // 2
 
 
 def test_ring_order_basics():
