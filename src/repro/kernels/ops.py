@@ -4,10 +4,17 @@
 engine uses the pure-jnp drain on every backend — Mosaic cannot lower
 the drain kernel (``kernels/drain_tick.py``) — and these wrappers are
 exercised by the per-kernel allclose sweeps in tests/.
+
+The engine drains through :func:`drain_tick_live`: each tick it packs
+the pool's live slots, in slot order, into the smallest width of a
+ladder (:func:`drain_widths`: M, M/2, ..., M/16) that holds every
+member's live slots, runs :func:`drain_tick` there, and puts the
+per-slot results back. The top rung, M, is the full-pool pass itself.
 """
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +78,90 @@ def drain_tick(routes, bytes_rem, active, job, min_arrive, t, dt, bw_eff,
         link_dst_router, n_apps, n_routers, interpret=interpret,
     )
     return new_rem[:, :M], rate[:, :M], delivered[:, :M], lb, rw
+
+
+# the drain's ladder of widths: the pool size, then halvings down to a
+# sixteenth, none narrower than DRAIN_MIN_WIDTH slots (M always stays)
+DRAIN_RUNGS = 5
+DRAIN_MIN_WIDTH = 256
+
+
+def drain_widths(M: int) -> Tuple[int, ...]:
+    """Drain widths for a pool of ``M`` slots, widest first."""
+    return (M,) + tuple(M >> i for i in range(1, DRAIN_RUNGS)
+                        if M >> i >= DRAIN_MIN_WIDTH)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_apps", "n_routers", "use_pallas", "interpret"),
+)
+def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
+                    bw_eff, link_dst_router, *, n_apps: int, n_routers: int,
+                    use_pallas: bool = False, interpret: bool = True):
+    """:func:`drain_tick` over the live pool slots only.
+
+    Picks the smallest width ``W`` of ``drain_widths(M)`` that holds the
+    most live slots of any member, gathers each member's live slots in
+    ascending slot order into ``(B, W)`` (padding slots inactive), drains
+    them, and scatters ``new_rem``, ``rate`` and ``delivered`` back to
+    ``(B, M)``; every other slot keeps its ``bytes_rem``, rate 0 and is
+    not delivered. The link and router deltas keep their full shapes.
+    Every real link sees the same valid entries in the same order, and
+    the dropped entries added 0 to the dummy link, so the outputs equal
+    the full-pool pass (bit for bit where scatter-adds run in index
+    order, as on CPU) for any pool whose ``bytes_rem`` are >= 0.
+
+    Returns ``drain_tick``'s five outputs and the rung taken, an int32
+    index into ``drain_widths(M)``. The top rung is ``drain_tick`` on
+    the whole pool, with nothing gathered or scattered around it.
+    """
+    B, M, K = routes.shape
+    widths = drain_widths(M)
+    drain = functools.partial(
+        drain_tick, t=t, dt=dt, bw_eff=bw_eff,
+        link_dst_router=link_dst_router, n_apps=n_apps,
+        n_routers=n_routers, use_pallas=use_pallas, interpret=interpret)
+
+    def packed(W):
+        # live slot m of member b goes to column cumsum(active)[b, m] - 1
+        # (below W: the rung holds every member's live slots); columns
+        # past a member's live count hold the index M (no slot)
+        col = jnp.cumsum(active, axis=1, dtype=jnp.int32) - 1
+        to = jnp.where(active & (col < W),
+                       jnp.arange(B, dtype=jnp.int32)[:, None] * W + col,
+                       B * W)
+        slot = (jnp.full((B * W,), M, jnp.int32)
+                .at[to.reshape(-1)]
+                .set(jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32),
+                                      (B, M)).reshape(-1), mode="drop")
+                .reshape(B, W))
+        live = slot < M
+        at = jnp.minimum(slot, M - 1)
+
+        def rows(x):
+            ix = at.reshape(at.shape + (1,) * (x.ndim - 2))
+            return jnp.take_along_axis(x, ix, axis=1)
+
+        new_rem, rate, delivered, lb, rw = drain(
+            rows(routes), rows(bytes_rem), live, rows(job), rows(min_arrive))
+        back = jnp.where(
+            live, jnp.arange(B, dtype=jnp.int32)[:, None] * M + slot,
+            B * M).reshape(-1)
+
+        def put(base, x):
+            return (base.reshape(-1).at[back].set(x.reshape(-1), mode="drop")
+                    .reshape(B, M))
+
+        return (put(bytes_rem, new_rem), put(jnp.zeros_like(bytes_rem), rate),
+                put(jnp.zeros_like(active), delivered), lb, rw)
+
+    n_live = jnp.max(jnp.sum(active, axis=1, dtype=jnp.int32))
+    rung = jnp.sum(n_live <= jnp.asarray(widths[1:], jnp.int32),
+                   dtype=jnp.int32)
+    branches = [lambda _: drain(routes, bytes_rem, active, job, min_arrive)]
+    branches += [(lambda _, W=W: packed(W)) for W in widths[1:]]
+    return jax.lax.switch(rung, branches, None) + (rung,)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))

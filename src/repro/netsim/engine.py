@@ -10,7 +10,9 @@ One `tick` advances Δt of virtual time:
      routes (MIN or adaptive, live link demand) and latency floors.
   3. **Network**: fluid fair-share wormhole model — the fused drain tick
      (`kernels/drain_tick.py`): link demand → fair-share rate →
-     per-message drain → delivery mask in one pass.
+     per-message drain → delivery mask in one pass, over the live pool
+     slots packed into the smallest of M, M/2, ..., M/16 slots that holds
+     them (`kernels.ops.drain_tick_live`).
   4. **Bookkeeping**: deliveries unblock VMs (cumulative counting — see
      DESIGN §9 for the matching relaxation); latency histograms, per-app
      router-window counters (paper's 0.5 ms packet counters), link loads.
@@ -145,6 +147,9 @@ class Metrics(NamedTuple):
     # overflow at 65,536 slots x 32,768 ticks)
     active_slot_ticks: jnp.ndarray
     peak_active: jnp.ndarray  # int32 most pool slots active at once
+    # (n_rungs,) int32 live ticks drained at each width of
+    # ``kernels.ops.drain_widths(M)`` (widest, the whole pool, first)
+    drain_rung_ticks: jnp.ndarray
 
 
 class SimState(NamedTuple):
@@ -504,9 +509,12 @@ def build_engine(
     job lands on a network already carrying traffic.
 
     The drain tick is the jnp fused path (``kernels/ref.drain_tick_ref``)
-    on every backend. ``use_pallas=True`` opts into the Pallas kernel:
-    interpret mode off-TPU (the CPU tests), Mosaic on a TPU, where jax
-    0.9.0 refuses its in-kernel scatter-add (``kernels/drain_tick.py``).
+    on every backend, run each tick over the live pool slots at the
+    smallest width of ``kernels.ops.drain_widths(M)`` that holds them
+    (the top rung, ``M``, is the full-pool pass). ``use_pallas=True``
+    opts into the Pallas kernel: interpret mode off-TPU (the CPU
+    tests), Mosaic on a TPU, where jax 0.9.0 refuses its in-kernel
+    scatter-add (``kernels/drain_tick.py``).
 
     ``probes`` compiles in the sim-plane observation rings
     (:mod:`repro.obs.probes`): per-level link utilization, per-app
@@ -530,6 +538,7 @@ def build_engine(
     BINS = net.latency_hist_bins
     W = net.max_windows
     R = topo.n_routers
+    n_rungs = len(KOPS.drain_widths(M))
     # an opted-in Pallas drain compiles Mosaic on a TPU and is emulated
     # in interpret mode elsewhere
     kernel_interpret = jax.default_backend() != "tpu"
@@ -971,9 +980,13 @@ def build_engine(
                 )
 
         # --- 2-3. fused drain tick: demand -> fair share -> drain ->
-        # delivery, plus per-link byte counters (kernels/drain_tick.py) ---
+        # delivery, plus per-link byte counters (kernels/drain_tick.py),
+        # over the live slots only: packed into the smallest rung of
+        # M, M/2, ..., M/16 that holds every member's live slots (the top
+        # rung is the full-pool pass), results put back per slot ---
         with jax.named_scope("tick/drain"):
-            new_rem, _rate, delivered, lb_delta, rw_delta = KOPS.drain_tick(
+            (new_rem, _rate, delivered, lb_delta, rw_delta,
+             drain_rung) = KOPS.drain_tick_live(
                 pool.routes, pool.bytes_rem, pool.active, pool.job,
                 pool.min_arrive, t, jnp.float32(dt), bw_run, link_dstr,
                 n_apps=n_apps, n_routers=R, use_pallas=use_pallas,
@@ -1172,6 +1185,9 @@ def build_engine(
                 + (n_active * live_i).astype(jnp.float32),
                 peak_active=jnp.maximum(
                     metrics.peak_active, n_active * live_i),
+                drain_rung_ticks=metrics.drain_rung_ticks
+                + (jnp.arange(n_rungs) == drain_rung).astype(jnp.int32)
+                * live_i[:, None],
             )
 
         # --- 9. sim-plane probes (compiled in only when requested) ---
@@ -1288,6 +1304,7 @@ def build_engine(
             inject_ticks=jnp.int32(0),
             active_slot_ticks=jnp.float32(0.0),
             peak_active=jnp.int32(0),
+            drain_rung_ticks=jnp.zeros((n_rungs,), jnp.int32),
         )
         if faults is None:
             flt = FaultState(

@@ -49,6 +49,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 import jax
 import numpy as np
 
+from repro.kernels.ops import drain_widths
 from repro.netsim.engine import (
     engine_cache_stats,
     get_engine,
@@ -628,11 +629,16 @@ class RunCancelled(RuntimeError):
 # ---------------------------------------------------------------------------
 
 def _engine_counts(state):
-    """Each member's ``ticks``, ``inject_ticks`` and ``active_slot_ticks``
-    (float64, flat over any leading axes)."""
-    m = jax.device_get((state.metrics.ticks, state.metrics.inject_ticks,
-                        state.metrics.active_slot_ticks))
-    return [np.asarray(x, np.float64).reshape(-1) for x in m]
+    """Each member's ``ticks``, ``inject_ticks``, ``active_slot_ticks``
+    and drained slot-ticks (Σ over the drain's rungs of ticks x width),
+    float64, flat over any leading axes."""
+    m = state.metrics
+    ticks, inject, active, rungs = jax.device_get(
+        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.drain_rung_ticks))
+    widths = np.asarray(drain_widths(state.pool.active.shape[-1]), np.float64)
+    drained = np.asarray(rungs, np.float64).reshape(-1, widths.size) @ widths
+    return [np.asarray(x, np.float64).reshape(-1)
+            for x in (ticks, inject, active)] + [drained]
 
 
 def _count_run(eng, final, *args, before=None, program=True):
@@ -651,13 +657,14 @@ def _count_run(eng, final, *args, before=None, program=True):
         leaves, tree = jax.tree_util.tree_flatten(spec)
         note_program((id(eng.run), tree, tuple(leaves)),
                      eng.phase_map, *spec)
-    ticks, inject, active = _engine_counts(final)
+    ticks, inject, active, drained = _engine_counts(final)
     if before is not None:
-        ticks, inject, active = (a - b for a, b in zip(
-            (ticks, inject, active), before))
+        ticks, inject, active, drained = (a - b for a, b in zip(
+            (ticks, inject, active, drained), before))
     counter("engine.counters", iterations=ticks.max(),
             member_ticks=ticks.sum(), inject_ticks=inject.sum(),
-            active_slot_ticks=active.sum())
+            active_slot_ticks=active.sum(),
+            drain_slot_ticks=drained.sum())
 
 
 def _run_faulted(eng, inits, cells, host):

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.core import workloads as W
 from repro.core.translator import translate_source
+from repro.kernels.ops import drain_widths
 from repro.netsim import metrics as MET
 from repro.netsim.config import NetConfig
 from repro.netsim.engine import (
@@ -229,14 +230,18 @@ def member_report(state, rs: ResolvedScenario, wall_s: float = 0.0,
         envelope=dict(Jmax=cap.Jmax, Pmax=cap.Pmax, OPmax=cap.OPmax),
     )
     m = state.metrics
-    ticks, inject_ticks, active, peak = jax.device_get(
-        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.peak_active))
+    ticks, inject_ticks, active, peak, rungs = jax.device_get(
+        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.peak_active,
+         m.drain_rung_ticks))
     ticks, slots = int(ticks), int(state.pool.active.shape[-1])
     rep["engine"] = dict(
         ticks=ticks, inject_ticks=int(inject_ticks),
         mean_pool_occupancy=(float(active) / (ticks * slots)
                              if ticks else 0.0),
         peak_pool_occupancy=int(peak) / slots,
+        mean_drain_width=(
+            int(np.dot(rungs, np.asarray(drain_widths(slots), np.int64)))
+            / (ticks * slots) if ticks else 0.0),
     )
     if getattr(state, "probes", None) is not None:
         from repro.obs import probe_timelines

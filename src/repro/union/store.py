@@ -37,7 +37,8 @@ import numpy as np
 # Bump when engine semantics change in a way the fingerprint inputs do
 # not capture (a changed store version invalidates every prior entry).
 # 2: member reports carry the engine's tick counters (``report["engine"]``).
-STORE_VERSION = 2
+# 3: ``report["engine"]`` carries the drain's ``mean_drain_width``.
+STORE_VERSION = 3
 
 _VERSIONS: Optional[Dict[str, Any]] = None
 

@@ -4,6 +4,9 @@ host-plane tracing that reads them.
 * ``Metrics.ticks`` / ``inject_ticks`` / ``active_slot_ticks`` /
   ``peak_active`` equal a host replay that steps ``eng.tick`` one tick
   at a time, for a batch whose members finish on different ticks;
+* ``Metrics.drain_rung_ticks`` counts each member's live ticks at the
+  drain width it took, as a host replay of the chosen widths does, and
+  ``report["engine"]["mean_drain_width"]`` reads it;
 * ``Engine.phase_map`` names every ``tick/<phase>`` scope;
 * spans mirror onto a ``jax.profiler`` trace's host plane only while
   tracing, and the facade emits ``engine.counters`` only then.
@@ -18,9 +21,11 @@ import pytest
 import jax
 
 from repro import obs, union
-from repro.netsim.engine import hlo_phases, stack_members
+from repro.kernels.ops import drain_widths
+from repro.netsim.engine import hlo_phases, member_state, stack_members
 from repro.obs import ProbeConfig
 from repro.union import manager as MGR
+from repro.union.scenario import Scenario, ScenarioJob, URDecl
 
 import test_engine_equivalence as EQ
 import test_obs as OBS
@@ -100,6 +105,61 @@ def test_report_engine_block(mixed):
         float(st.metrics.active_slot_ticks) / (blk["ticks"] * M))
     assert 0.0 < blk["mean_pool_occupancy"] <= blk["peak_pool_occupancy"]
     assert blk["peak_pool_occupancy"] == int(st.metrics.peak_active) / M
+
+
+def test_drain_rung_ticks_count_live_ticks(mixed):
+    """Each member counts one rung a live tick: the member that stops
+    first stops counting while the loop runs on for the other."""
+    eng, state = mixed
+    m = jax.device_get(eng.run(state)).metrics
+    assert m.ticks[0] != m.ticks[1]
+    np.testing.assert_array_equal(m.drain_rung_ticks.sum(axis=1), m.ticks)
+
+
+def _bursty_scenario():
+    """A UR source of 480 ranks firing 256 KiB every 200 us over a pool of
+    1,024 slots: the live count crosses both narrower rungs (512, 256)."""
+    return Scenario(
+        name="drain-rungs",
+        jobs=[ScenarioJob(app="ar8", source=EQ.AR, ranks=8)],
+        placement="RN", routing="ADP",
+        ur=URDecl(ranks=480, size_bytes=262144.0, interval_us=200.0),
+        tick_us=2.0, horizon_ms=0.6, pool_size=1024,
+    )
+
+
+def test_mean_drain_width_equals_a_host_replay():
+    rs = MGR.resolve(_bursty_scenario(), seed=3)
+    eng = MGR.build(rs)
+    state = stack_members([eng.init_state(seed=1),
+                           eng.init_state(seed=2, start_us=[300.0])])
+    widths = drain_widths(1024)
+    tick = jax.jit(eng.tick)
+    s = jax.device_get(state)
+    rungs = np.zeros((2, len(widths)), np.int64)
+    horizon = rs.scenario.horizon_ms * 1000.0
+    while True:
+        done = s.vms.done.all(axis=(1, 2)) & ~s.pool.active.any(axis=1)
+        live = (s.t < horizon) & ~done
+        if not live.any():
+            break
+        nxt = jax.device_get(tick(s))
+        # slots the drain saw = active after the tick + delivered in it;
+        # the batch drains at the narrowest width holding its fullest member
+        delivered = nxt.metrics.lat_cnt.sum(1) - s.metrics.lat_cnt.sum(1)
+        seen = (nxt.pool.active.sum(1) + delivered).max()
+        rung = max(i for i, w in enumerate(widths) if seen <= w)
+        rungs[:, rung] += live
+        s = nxt
+    final = jax.device_get(eng.run(state))
+    np.testing.assert_array_equal(final.metrics.drain_rung_ticks, rungs)
+    assert (rungs > 0).sum(axis=1).min() >= 2  # the width changes
+    for b in range(2):
+        blk = MGR.member_report(member_state(final, b), rs)["engine"]
+        ticks = int(final.metrics.ticks[b])
+        assert blk["mean_drain_width"] == pytest.approx(
+            float(rungs[b] @ np.asarray(widths)) / (ticks * 1024), rel=1e-12)
+        assert blk["mean_pool_occupancy"] <= blk["mean_drain_width"] < 1.0
 
 
 def test_phase_map_names_every_phase():
@@ -203,6 +263,13 @@ def test_engine_counters_event_only_with_spans(tracer, vmapped):
     assert sum(e["iterations"] for e in evs) == (
         max(ticks) if vmapped else sum(ticks))
     assert all(e["active_slot_ticks"] > 0 for e in evs)
+    # the drain's slot-ticks: Σ ticks x width, whole numbers, one a run
+    M = OBS.tiny_scenario().pool_size
+    assert all(e["drain_slot_ticks"] == int(e["drain_slot_ticks"]) > 0
+               for e in evs)
+    assert sum(e["drain_slot_ticks"] for e in evs) == round(sum(
+        c.report["engine"]["mean_drain_width"] * c.report["engine"]["ticks"]
+        * M for c in res.cells))
     names = {e["name"] for e in tr.events}
     assert {"planner.resolve", "engine.report"} <= names
     pm = obs.device_phases()
