@@ -22,7 +22,8 @@ process, each through the user's entry point ``repro.union.run``.
 (d) scheduler windows: the batched ``TraceStudy`` of
     ``benchmarks/bench_union.py --trace --quick``; every job completes.
 (e) four chips (``--chips 4`` only): the phase (c) ensemble at 8 members,
-    ``pmap``-ed over the chips, against the same members run one by one
+    its member axis sharded over the chips (``union.run`` through
+    ``experiment.run_members``), against the same members run one by one
     on one chip. The final state must sit on 4 distinct devices and the
     per-member reports must equal the one-chip run.
 
@@ -316,8 +317,9 @@ def _same_report(a: dict, b: dict, tag: str) -> None:
 def phase_four_chips(scale: str = "paper",
                      horizon_ms: float = PAPER_HORIZON_MS,
                      members: int = FOUR_CHIP_MEMBERS) -> dict:
-    """``members`` through ``union.run`` sharded over every local device
-    (``Engine.prun``), against the same members one by one on one."""
+    """``members`` through ``union.run``, their member axis sharded over
+    every local device (``Engine.run_sharded`` on the member mesh),
+    against the same members one by one on one."""
     import jax
 
     from repro import union

@@ -32,7 +32,10 @@ to `B=1`) or a stacked batch; all scatters fold the member index into one
 flat index so an 8-member campaign costs one scatter per pass, not eight
 serialized ones. Member *i* of a batched run is bit-identical to its own
 `B=1` run, and to the historical per-job-loop engine (the equivalence
-goldens in tests/ assert this).
+goldens in tests/ assert this). Across several local devices,
+:meth:`Engine.run_sharded` runs the same loop under ``shard_map`` over a
+one-axis ``members`` mesh (:func:`member_mesh`): each device steps its
+own members, with no collectives.
 
 **Slot recycling** (the online-scheduler substrate, `repro.sched`): job
 slots are a reusable resource. `run_window(state, t_stop)` advances until
@@ -52,6 +55,7 @@ import math
 import os
 import re
 import warnings
+import weakref
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple
@@ -60,6 +64,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.skeleton import OP, SkeletonProgram
 from repro.kernels import ops as KOPS
@@ -239,29 +244,56 @@ class Engine:
     tick: Callable
     run_window: Callable
     capacity: EngineCapacity
-    _prun: Optional[Callable] = None
     _lower: Optional[Callable] = None
 
     def __iter__(self):
         return iter((self.init_state, self.run, self.tick))
 
-    @property
-    def prun(self) -> Callable:
-        """``run`` pmapped over a leading device axis, built lazily and
-        memoized on the engine so every campaign at this envelope shares
-        one pmap cache entry."""
-        if self._prun is None:
-            self._prun = jax.pmap(self.run)
-        return self._prun
+    def run_sharded(self, mesh: Mesh) -> Callable:
+        """``run`` over a batch whose every leaf is sharded on its leading
+        member axis over ``mesh`` (:func:`member_mesh`): each device
+        runs the while loop over its own members, with no collectives.
+        Built once per (``run``, mesh), so every engine wrapper that
+        shares this ``run`` shares one program."""
+        progs = _SHARDED_RUNS.setdefault(self.run, {})
+        if mesh not in progs:
+            spec = PartitionSpec(MEMBER_AXIS)
+            progs[mesh] = jax.jit(jax.shard_map(
+                self.run, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                check_vma=False))
+        return progs[mesh]
 
     def phase_map(self, state, t_stop=None) -> Dict[str, str]:
         """``{HLO instruction name: tick phase}`` of the run program
         compiled for the shapes of ``state`` (``run_window``'s when
-        ``t_stop`` is given). Compiling again hits the compile cache.
-        A profile's device ops carry these instruction names, so the map
-        labels each op with the ``tick/<phase>`` scope it was traced in
-        (docs/obs.md)."""
-        return hlo_phases(self._lower(state, t_stop).compile().as_text())
+        ``t_stop`` is given; :meth:`run_sharded`'s when ``state``'s
+        leaves carry a sharding over a member mesh). Compiling again
+        hits the compile cache. A profile's device ops carry these
+        instruction names, so the map labels each op with the
+        ``tick/<phase>`` scope it was traced in (docs/obs.md)."""
+        mesh = getattr(getattr(state.t, "sharding", None), "mesh", None)
+        if t_stop is None and mesh is not None and mesh.size > 1:
+            lowered = self.run_sharded(mesh).lower(state)
+        else:
+            lowered = self._lower(state, t_stop)
+        return hlo_phases(lowered.compile().as_text())
+
+
+MEMBER_AXIS = "members"
+# run -> {mesh: sharded program}; an entry goes with its engine's run
+_SHARDED_RUNS: "weakref.WeakKeyDictionary[Callable, Dict]" = (
+    weakref.WeakKeyDictionary())
+
+
+def member_mesh(n_members: int) -> Optional[Mesh]:
+    """The one-axis ``members`` mesh of the local devices over which a
+    batch of ``n_members`` is sharded, or None where it runs on one
+    device: there is one local device, or the members do not divide
+    evenly among them."""
+    devs = jax.local_devices()
+    if len(devs) > 1 and n_members % len(devs) == 0:
+        return Mesh(np.asarray(devs), (MEMBER_AXIS,))
+    return None
 
 
 def _ceil_log2(P: int) -> int:
@@ -371,6 +403,10 @@ _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(
     r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(([^)]*)\)")
 _HLO_SUBCOMPUTATION = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+# computations a while loop or a conditional runs
+_HLO_CONTROL = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
 _HLO_OPERAND = re.compile(r"%?([\w.\-]+)")
 _HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
 _TICK_PHASE = re.compile(r"(?:^|/)tick/(\w+)")
@@ -389,7 +425,9 @@ def hlo_phases(hlo_text: str) -> Dict[str, str]:
     nearest the root. Data movement the compiler inserted with no
     metadata (copies, prefetches, layout changes outside any fusion)
     takes the phase of the first instruction it feeds, else of the one
-    that feeds it.
+    that feeds it; so does an instruction of a while loop's body (or of
+    a branch it runs) whose metadata names no tick scope, as the SPMD
+    partitioner leaves what it rebuilds under a ``shard_map``.
     """
     own: Dict[str, Optional[str]] = {}
     calls: Dict[str, str] = {}
@@ -398,8 +436,10 @@ def hlo_phases(hlo_text: str) -> Dict[str, str]:
     comp_of: Dict[str, str] = {}
     opcode: Dict[str, str] = {}
     operands: Dict[str, list] = {}
-    bare = []  # instructions with no op_name: the compiler added them
+    bare = set()  # instructions with no op_name: the compiler added them
     inner = set()  # computations called by fusions and reductions
+    control: Dict[str, set] = {}  # computation -> loop bodies, branches
+    loops = set()  # computations run as a while loop's body or condition
     comp = None
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -415,7 +455,7 @@ def hlo_phases(hlo_text: str) -> Dict[str, str]:
             roots[comp] = name
         op = _HLO_OP_NAME.search(line)
         if op is None:
-            bare.append(name)
+            bare.add(name)
         ph = _TICK_PHASE.search(op.group(1)) if op else None
         own[name] = ph.group(1) if ph else None
         if ph is not None:
@@ -424,6 +464,11 @@ def hlo_phases(hlo_text: str) -> Dict[str, str]:
             inner.add(sub)
             if m.group(3) == "fusion":
                 calls[name] = sub
+        for one, many in _HLO_CONTROL.findall(line):
+            subs = [one] if one else _HLO_OPERAND.findall(many)
+            control.setdefault(comp, set()).update(subs)
+            if m.group(3) == "while":
+                loops.update(subs)
 
     def phase(name, depth=0):
         callee = calls.get(name)
@@ -437,7 +482,14 @@ def hlo_phases(hlo_text: str) -> Dict[str, str]:
     for n, ops in operands.items():
         for o in ops:
             users.setdefault(o, []).append(n)
-    loose = [n for n in bare if n not in out and comp_of[n] not in inner
+    todo = list(loops)
+    while todo:  # every computation a loop runs, through its branches
+        for sub in control.get(todo.pop(), ()):
+            if sub not in loops:
+                loops.add(sub)
+                todo.append(sub)
+    loose = [n for n in own if (n in bare or comp_of[n] in loops)
+             and n not in out and comp_of[n] not in inner
              and opcode[n] not in _HLO_STRUCTURAL]
     for near in (users, operands):  # what it feeds first, then its source
         while loose:
@@ -1613,6 +1665,40 @@ def member_state(batched_state: SimState, i: int) -> SimState:
 def stack_members(states: Sequence[SimState]) -> SimState:
     """Stack member states into one batch (leading member dim)."""
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
+
+
+# one program for every leaf and member where eager indexing or stacking
+# would dispatch once a leaf and member: on a host driving four chips
+# those dispatches took most of a call's placement and split
+@jax.jit
+def _stack(states):
+    return stack_members(states)
+
+
+@jax.jit
+def _unstack(batch):
+    return [member_state(batch, j) for j in range(batch.t.shape[0])]
+
+
+def shard_members(states: Sequence[SimState], mesh: Mesh) -> SimState:
+    """Stack member states into one batch sharded on its leading member
+    axis over ``mesh`` (:func:`member_mesh`), ``len(states) / mesh.size``
+    members a device, in member order."""
+    return jax.device_put(_stack(list(states)), NamedSharding(
+        mesh, PartitionSpec(MEMBER_AXIS)))
+
+
+def split_members(batched_state: SimState) -> list:
+    """Every member of a batched state, in member order, each left on
+    the device that holds it: each device's members are unstacked by one
+    program on that device."""
+    leaves, tree = jax.tree_util.tree_flatten(batched_state)
+    shards = [sorted(x.addressable_shards,
+                     key=lambda s: s.index[0].start or 0) for x in leaves]
+    out = []
+    for local in zip(*shards):
+        out += _unstack(tree.unflatten([s.data for s in local]))
+    return out
 
 
 # ---------------------------------------------------------------------------

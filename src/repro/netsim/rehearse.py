@@ -13,8 +13,9 @@ kernel); this module's ``__main__`` rehearses the paper's widths::
 
 It compiles, for one chip of a v5e:2x2, the jnp drain tick at the 2-D
 dragonfly's widths, then ``engine.run`` and ``run_window`` for the
-paper's workload1 on the 1-D dragonfly (about two minutes on a few
-cores).
+paper's workload1 on the 1-D dragonfly; and for all four chips, the
+member-sharded run of 8 such members, 2 a chip (about four minutes on a
+few cores).
 """
 from __future__ import annotations
 
@@ -25,7 +26,9 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+import numpy as np
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.kernels import ops as KOPS
 
@@ -91,6 +94,17 @@ def compile_run_window(eng, state, sharding):
         abstract(state, sharding), t_stop).compile()
 
 
+def compile_run_sharded(eng, state, topo):
+    """``engine.run_sharded`` on a mesh of every chip of ``topo``, the
+    batch ``state`` sharded on its member axis as ``union.run`` places
+    it."""
+    from repro.netsim.engine import MEMBER_AXIS
+
+    mesh = Mesh(np.asarray(topo.devices), (MEMBER_AXIS,))
+    sharding = NamedSharding(mesh, PartitionSpec(MEMBER_AXIS))
+    return eng.run_sharded(mesh).lower(abstract(state, sharding)).compile()
+
+
 def scenario_engine(sc, members: int = 1):
     """The engine ``union.run`` draws for a ``members``-ensemble of
     ``sc`` and the stacked initial states it runs."""
@@ -130,7 +144,8 @@ def main(argv=None) -> int:
                                  description=__doc__.split("\n\n")[0])
     ap.parse_args(argv)
     jax.config.update("jax_enable_compilation_cache", False)
-    chip = one_chip(describe())
+    topo = describe()
+    chip = one_chip(topo)
     print(f"described {TOPOLOGY}: "
           f"{next(iter(chip.device_set)).device_kind}")
     ok = _report("jnp drain tick, 2-D paper widths",
@@ -142,6 +157,10 @@ def main(argv=None) -> int:
                   lambda: compile_run(eng, state, chip))
     ok &= _report("engine.run_window, same envelope",
                   lambda: compile_run_window(eng, state, chip))
+    eng, state = scenario_engine(sc, members=8)
+    ok &= _report(f"engine.run_sharded, same envelope, 8 members over "
+                  f"the {TOPOLOGY}",
+                  lambda: compile_run_sharded(eng, state, topo))
     return 0 if ok else 1
 
 

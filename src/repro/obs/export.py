@@ -96,9 +96,16 @@ def chrome_events(events: Optional[List[Dict[str, Any]]] = None,
     out = []
     for ev in events:
         if ev.get("ph") == "C":
+            # a counter track plots numbers: a list becomes one per entry
+            args = {}
+            for k, v in ev.get("args", {}).items():
+                if isinstance(v, list):
+                    args.update((f"{k}[{i}]", x) for i, x in enumerate(v))
+                else:
+                    args[k] = v
             out.append(dict(
                 name=ev["name"], ph="C", ts=ev["ts_us"], pid=pid, tid=0,
-                args=ev.get("args", {}),
+                args=args,
             ))
             continue
         ce: Dict[str, Any] = dict(

@@ -119,14 +119,16 @@ class Tracer:
         with self._lock:
             self.events.append(ev)
 
-    def counter(self, name: str, **values: float) -> None:
-        """A Chrome counter ('C') sample — e.g. cache hit totals over time."""
+    def counter(self, name: str, **values) -> None:
+        """A Chrome counter ('C') sample — e.g. cache hit totals over time.
+        A value is a number or a list of numbers (one a device)."""
         if not self.enabled:
             return
         ev = dict(
             name=name, cat="counter", ph="C",
             ts_us=(time.perf_counter_ns() - self.origin_ns) / 1000.0,
-            args={k: float(v) for k, v in values.items()},
+            args={k: ([float(x) for x in v] if isinstance(v, (list, tuple))
+                      else float(v)) for k, v in values.items()},
         )
         with self._lock:
             self.events.append(ev)
@@ -192,7 +194,7 @@ def span(name: str, /, cat: str = "host", **args):
         tr.record(name, cat, t0, dur, cpu, handle.args)
 
 
-def counter(name: str, **values: float) -> None:
+def counter(name: str, **values) -> None:
     _TRACER.counter(name, **values)
 
 

@@ -53,7 +53,10 @@ from repro.kernels.ops import drain_widths
 from repro.netsim.engine import (
     engine_cache_stats,
     get_engine,
+    member_mesh,
     member_state,
+    shard_members,
+    split_members,
     stack_members,
 )
 from repro.obs import (
@@ -641,27 +644,36 @@ def _engine_counts(state):
             for x in (ticks, inject, active)] + [drained]
 
 
-def _count_run(eng, final, *args, before=None, program=True):
+def _shape_of(x):
+    """A run input's shape and dtype, with its sharding where that spans
+    several devices: the member-sharded program is lowered for it."""
+    sh = getattr(x, "sharding", None)
+    if sh is None or len(sh.device_set) == 1:
+        sh = None
+    return jax.ShapeDtypeStruct(np.shape(x), np.result_type(x), sharding=sh)
+
+
+def _count_run(eng, final, *args, before=None):
     """While spans are on, and only then: note the run's program for
     ``repro.obs.device_phases`` (``args`` are the run's inputs after the
-    state, whose shapes ``final`` shares) and emit one
+    state, whose shapes and shardings ``final`` shares) and emit one
     ``engine.counters`` event for the run that produced ``final``, less
     the counts ``before`` it. ``iterations`` is the largest member's
-    live ticks: the while loop runs until its last member stops."""
+    live ticks: the while loop runs until its last member stops.
+    ``chip_iterations`` is the same per device, in mesh order, where
+    the members are sharded over several (one entry otherwise)."""
     if not tracing():
         return
-    if program:
-        spec = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(np.shape(x), np.result_type(x)),
-            (final,) + args)
-        leaves, tree = jax.tree_util.tree_flatten(spec)
-        note_program((id(eng.run), tree, tuple(leaves)),
-                     eng.phase_map, *spec)
+    spec = jax.tree_util.tree_map(_shape_of, (final,) + args)
+    leaves, tree = jax.tree_util.tree_flatten(spec)
+    note_program((id(eng.run), tree, tuple(leaves)), eng.phase_map, *spec)
     ticks, inject, active, drained = _engine_counts(final)
     if before is not None:
         ticks, inject, active, drained = (a - b for a, b in zip(
             (ticks, inject, active, drained), before))
+    chips = len(final.t.sharding.device_set) if ticks.size > 1 else 1
     counter("engine.counters", iterations=ticks.max(),
+            chip_iterations=ticks.reshape(chips, -1).max(axis=1).tolist(),
             member_ticks=ticks.sum(), inject_ticks=inject.sum(),
             active_slot_ticks=active.sum(),
             drain_slot_ticks=drained.sum())
@@ -738,6 +750,31 @@ def node_engine(node, exp: Experiment):
     return eng, inits, cold
 
 
+def run_members(eng, inits) -> List[Any]:
+    """Run member states to the horizon in one call of ``eng``; their
+    final states, in member order. Where the local devices are more than
+    one and share the members evenly (``member_mesh``), the stacked
+    members are placed on the member mesh, sharded on their leading
+    axis, and run by ``eng.run_sharded``; otherwise they run as one
+    batch on one device. Calling it on states already at the horizon
+    compiles, without simulating, every program a ``union.run`` of
+    these shapes runs here."""
+    mesh = member_mesh(len(inits))
+    if mesh is None:
+        # one device: the batch is stacked and indexed in place, op by op
+        final = jax.block_until_ready(eng.run(stack_members(inits)))
+        _count_run(eng, final)
+        return [member_state(final, i) for i in range(len(inits))]
+    with span("engine.shard", cat="engine", members=len(inits),
+              devices=mesh.size):
+        batch = shard_members(inits, mesh)
+    final = jax.block_until_ready(eng.run_sharded(mesh)(batch))
+    _count_run(eng, final)
+    with span("engine.shard", cat="engine", members=len(inits),
+              devices=mesh.size):
+        return split_members(final)
+
+
 def _device_ids(state) -> set:
     """Ids of the devices that hold ``state``'s shards."""
     return {s.device.id for s in state.t.addressable_shards}
@@ -767,32 +804,10 @@ def _exec_batched(node, exp: Experiment):
               vmapped=exp.vmapped, timed_faults=len(timed_ix)):
         if plain_ix:
             p_inits = [inits[i] for i in plain_ix]
-            np_ = len(p_inits)
             if exp.vmapped:
-                D = jax.local_device_count()
-                if D > 1 and np_ % D == 0:
-                    # shard members across XLA devices (CPU host devices
-                    # or accelerator cores): each runs an (n/D)-batch.
-                    chunk = np_ // D
-                    sharded = stack_members([
-                        stack_members(p_inits[d * chunk:(d + 1) * chunk])
-                        for d in range(D)
-                    ])
-                    final = jax.block_until_ready(eng.prun(sharded))
-                    # pmap compiles its own program: counted, not mapped
-                    _count_run(eng, final, program=False)
-                    devices |= _device_ids(final)
-                    p_states = [
-                        member_state(member_state(final, i // chunk),
-                                     i % chunk)
-                        for i in range(np_)
-                    ]
-                else:
-                    final = jax.block_until_ready(
-                        eng.run(stack_members(p_inits)))
-                    _count_run(eng, final)
-                    devices |= _device_ids(final)
-                    p_states = [member_state(final, i) for i in range(np_)]
+                p_states = run_members(eng, p_inits)
+                for st in p_states:
+                    devices |= _device_ids(st)
             else:
                 p_states = []
                 for s in p_inits:

@@ -196,11 +196,9 @@ def bind_jobs(eng: Engine, rs: ResolvedScenario) -> Engine:
             faults=faults,
         )
 
-    # share the host's pmapped run (built lazily on the cached engine, so
-    # every wrapper at this envelope reuses one pmap cache entry)
     return Engine(
         init_state=init_state, run=eng.run, tick=eng.tick,
-        run_window=eng.run_window, capacity=eng.capacity, _prun=eng.prun,
+        run_window=eng.run_window, capacity=eng.capacity,
         _lower=eng._lower,
     )
 

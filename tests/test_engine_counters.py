@@ -194,6 +194,18 @@ def test_hlo_phases_rules():
         '"jit(f)/while/body/tick/emit/cond/branch_1_fun/mul"}',
         '  ROOT %x = f32[4]{0} add(%m, %m), metadata={op_name="jit(f)/add"}',
         "}",
+        "%loop_body (s: f32[4]) -> f32[4] {",
+        "  %lg = f32[4]{0} get-tuple-element(%s), index=0",
+        '  %rw = f32[4]{0} reduce-window(%lg), metadata={op_name='
+        '"jit(f)/shard_map/reduce-window.35"}',
+        '  ROOT %d = f32[4]{0} add(%rw, %rw), metadata={op_name="jit(f)/'
+        'shard_map/jit(g)/while/body/tick/deliver/add"}',
+        "}",
+        "%main (p0: f32[4]) -> f32[4] {",
+        '  %w = f32[4]{0} while(%p0), condition=%cond, body=%loop_body',
+        '  ROOT %out = f32[4]{0} copy(%w), metadata={op_name="jit(f)/'
+        'shard_map"}',
+        "}",
     ])
     pm = hlo_phases(text)
     # the fusion's root has no metadata: the scope nearest the root
@@ -202,6 +214,11 @@ def test_hlo_phases_rules():
     assert pm["cp"] == "drain"
     assert pm["m"] == "emit"
     assert "x" not in pm and "g" not in pm
+    # an op of a while loop's body left with no tick scope (as the SPMD
+    # partitioner leaves what it rebuilds) takes the phase of what it
+    # feeds; one outside the loop stays unnamed
+    assert pm["rw"] == "deliver" and pm["d"] == "deliver"
+    assert "out" not in pm
 
 
 def _host_events(path):

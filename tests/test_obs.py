@@ -223,7 +223,7 @@ def test_span_records_and_chrome_export(tmp_path, clean_tracer):
         sp.set(extra="v")
         with obs.span("inner", cat="test"):
             time.sleep(0.001)
-    obs.counter("pool", occ=0.5)
+    obs.counter("pool", occ=0.5, per_chip=[3, 4])
     clean_tracer.disable()
 
     assert clean_tracer.n_events == 3
@@ -244,7 +244,12 @@ def test_span_records_and_chrome_export(tmp_path, clean_tracer):
     assert len(X) == 2 and len(C) == 1
     for e in X:
         assert {"name", "cat", "ts", "dur", "pid", "tid"} <= set(e)
-    assert C[0]["args"] == {"occ": 0.5}
+    # a list value is kept as a list in the record, and becomes one
+    # numeric counter per entry in the Chrome trace
+    assert clean_tracer.events[2]["args"] == {"occ": 0.5,
+                                              "per_chip": [3.0, 4.0]}
+    assert C[0]["args"] == {"occ": 0.5, "per_chip[0]": 3.0,
+                            "per_chip[1]": 4.0}
 
     jl = str(tmp_path / "trace.jsonl")
     obs.write_jsonl(jl)
