@@ -10,6 +10,8 @@ the pool's live slots, in slot order, into the smallest width of a
 ladder (:func:`drain_widths`: M, M/2, ..., M/16) that holds every
 member's live slots, runs :func:`drain_tick` there, and puts the
 per-slot results back. The top rung, M, is the full-pool pass itself.
+The engine's injection packs the candidates that fired the same way
+(:func:`pack_columns`).
 """
 from __future__ import annotations
 
@@ -92,6 +94,22 @@ def drain_widths(M: int) -> Tuple[int, ...]:
                         if M >> i >= DRAIN_MIN_WIDTH)
 
 
+def pack_columns(mask, col, W: int):
+    """``(B, W)`` int32: the position ``i`` of each of member ``b``'s set
+    ``mask`` entries at column ``col[b, i]``, where that is below ``W``;
+    ``n`` (past the end of the ``(B, n)`` mask) in every other column.
+    ``col`` is ``cumsum(mask) - 1``, so the set entries keep their order
+    and fill the first columns."""
+    B, n = mask.shape
+    to = jnp.where(mask & (col < W),
+                   jnp.arange(B, dtype=jnp.int32)[:, None] * W + col, B * W)
+    return (jnp.full((B * W,), n, jnp.int32)
+            .at[to.reshape(-1)]
+            .set(jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32),
+                                  (B, n)).reshape(-1), mode="drop")
+            .reshape(B, W))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_apps", "n_routers", "use_pallas", "interpret"),
@@ -128,14 +146,7 @@ def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
         # (below W: the rung holds every member's live slots); columns
         # past a member's live count hold the index M (no slot)
         col = jnp.cumsum(active, axis=1, dtype=jnp.int32) - 1
-        to = jnp.where(active & (col < W),
-                       jnp.arange(B, dtype=jnp.int32)[:, None] * W + col,
-                       B * W)
-        slot = (jnp.full((B * W,), M, jnp.int32)
-                .at[to.reshape(-1)]
-                .set(jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32),
-                                      (B, M)).reshape(-1), mode="drop")
-                .reshape(B, W))
+        slot = pack_columns(active, col, W)
         live = slot < M
         at = jnp.minimum(slot, M - 1)
 

@@ -155,6 +155,10 @@ class Metrics(NamedTuple):
     # (n_rungs,) int32 live ticks drained at each width of
     # ``kernels.ops.drain_widths(M)`` (widest, the whole pool, first)
     drain_rung_ticks: jnp.ndarray
+    # int32 passes of ``inject_width(inject_candidates(state), M)``
+    # candidates routed over the injecting live ticks (a tick takes as
+    # many as its fullest member allocates)
+    inject_passes: jnp.ndarray
 
 
 class SimState(NamedTuple):
@@ -399,6 +403,128 @@ def _flat_max(target, idx, vals):
     return flat.at[gidx].max(vals.reshape(-1), mode="drop").reshape(shape)
 
 
+# ---------------------------------------------------------------------------
+# injection over the candidates that fired: packed, in emission order, into
+# passes of a fixed width, then routed and allocated there
+# ---------------------------------------------------------------------------
+
+# the width an injecting tick routes its fired candidates at; a tick whose
+# fullest member allocates more routes them in several passes of it. One
+# width, because every width compiles its own ``route_fn``: its TPU code
+# grows with the width and each one adds tracing to every start.
+INJECT_WIDTH = 1024
+
+
+def inject_width(n_cands: int, M: int) -> int:
+    """The width a member's ``n_cands`` candidates are routed at, a pass:
+    ``min(n_cands, M, INJECT_WIDTH)`` (a candidate at emission index
+    ``M`` or later never finds a free slot)."""
+    return min(n_cands, M, INJECT_WIDTH)
+
+
+def inject_candidates(state) -> int:
+    """A member's injection candidates a tick, ``Jmax * Pmax * MAXE`` job
+    candidates and the UR source's ``Pu``, from a member or batched
+    state's shapes."""
+    J, Pmax = state.vms.pc.shape[-2:]
+    Pu = 0 if state.ur is None else state.ur.next_t.shape[-1]
+    return int(J) * int(Pmax) * MAXE + int(Pu)
+
+
+def routed_candidates(state) -> np.ndarray:
+    """Each member's candidates routed over its injecting ticks (passes x
+    width), float64, flat over any leading axes."""
+    W = inject_width(inject_candidates(state), state.pool.active.shape[-1])
+    passes = jax.device_get(state.metrics.inject_passes)
+    return np.asarray(passes, np.float64).reshape(-1) * W
+
+
+class Cands(NamedTuple):
+    """The fields of packed candidates, ``(B, W)`` each."""
+
+    src_rank: jnp.ndarray  # int32
+    dst_rank: jnp.ndarray  # int32
+    src_node: jnp.ndarray  # int32
+    dst_node: jnp.ndarray  # int32
+    size: jnp.ndarray  # f32 bytes
+    app: jnp.ndarray  # int32 app id
+    rand: jnp.ndarray  # uint32 routing draw
+
+
+def inject_fired(pool: PoolState, t, mask, W: int, cands: Callable,
+                 route: Callable, hop_latency_us: float):
+    """Allocate pool slots to the candidates that fired and route them,
+    batched over members.
+
+    ``mask`` ``(B, n)`` marks the candidates that fired, in emission
+    order. They are packed, in that order, into consecutive columns and
+    routed ``W`` columns a pass, as many passes as the fullest member
+    allocates (``lax.fori_loop``). ``cands(idx)`` gives a :class:`Cands`
+    at the ``(B, W)`` candidate indices ``idx`` (``n`` in the padding
+    columns), and ``route(src_node, dst_node, rand)`` their ``(B, W, K)``
+    routes and ``(B, W)`` hop counts. A candidate's column is its
+    emission index ``k``: it takes slot ``free_stack[free_top - 1 - k]``
+    while ``k < free_top`` and is dropped after. The counts (``n_emit``,
+    allocated, ``dropped``) are taken over all ``n``.
+
+    Returns the pool, the ``(B, n)`` mask of the candidates allocated
+    and the passes (int32).
+    """
+    B, n = mask.shape
+    M = pool.active.shape[1]
+    free_top = pool.free_top[:, None]
+    col = jnp.cumsum(mask, axis=1, dtype=jnp.int32) - 1  # emission order
+    n_emit = jnp.sum(mask, axis=1, dtype=jnp.int32)
+    n_alloc = jnp.minimum(n_emit, pool.free_top)
+    # a column at or past min(n, M) never finds a slot
+    idx = KOPS.pack_columns(mask, col, -(-min(n, M) // W) * W)
+
+    def one_pass(i, leaves):
+        """Route and allocate columns ``i * W`` to ``(i + 1) * W``."""
+        active, src_rank, dst_rank, job, size, rem, inj, mina, rts = leaves
+        at = jax.lax.dynamic_slice_in_dim(idx, i * W, W, axis=1)
+        k = i * W + jnp.arange(W, dtype=jnp.int32)[None, :]
+        can = (at < n) & (k < free_top)
+        slot = jnp.take_along_axis(
+            pool.free_stack, jnp.clip(free_top - 1 - k, 0, M - 1), axis=1)
+        slot = jnp.where(can, slot, M)  # M = dummy row
+        c = cands(at)
+        routes, hops = route(c.src_node, c.dst_node, c.rand)
+        # route rows: scatter whole (K,) rows per slot
+        row = jnp.where(
+            can, slot + (jnp.arange(B, dtype=jnp.int32) * M)[:, None], B * M)
+        return (
+            _flat_set(active, slot, True, valid=can),
+            _flat_set(src_rank, slot, c.src_rank, valid=can),
+            _flat_set(dst_rank, slot, c.dst_rank, valid=can),
+            _flat_set(job, slot, c.app, valid=can),
+            _flat_set(size, slot, c.size, valid=can),
+            _flat_set(rem, slot, c.size, valid=can),
+            _flat_set(inj, slot, t[:, None], valid=can),
+            _flat_set(
+                mina, slot,
+                t[:, None] + hops.astype(jnp.float32) * hop_latency_us,
+                valid=can),
+            rts.reshape(B * M, -1).at[row.reshape(-1)].set(
+                routes.reshape(B * W, -1), mode="drop",
+            ).reshape(rts.shape),
+        )
+
+    passes = (jnp.max(n_alloc) + W - 1) // W
+    (active, src_rank, dst_rank, job, size, rem, inj, mina,
+     rts) = jax.lax.fori_loop(
+        0, passes, one_pass,
+        (pool.active, pool.src_rank, pool.dst_rank, pool.job, pool.size,
+         pool.bytes_rem, pool.inject_t, pool.min_arrive, pool.routes))
+    pool = pool._replace(
+        active=active, src_rank=src_rank, dst_rank=dst_rank, job=job,
+        size=size, bytes_rem=rem, inject_t=inj, min_arrive=mina,
+        routes=rts, free_top=pool.free_top - n_alloc,
+        dropped=pool.dropped + (n_emit - n_alloc),
+    )
+    return pool, mask & (col < free_top), passes
+
+
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(
     r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(([^)]*)\)")
@@ -568,6 +694,11 @@ def build_engine(
     tests), Mosaic on a TPU, where jax 0.9.0 refuses its in-kernel
     scatter-add (``kernels/drain_tick.py``).
 
+    Injecting ticks route and allocate only the candidates that fired,
+    the job candidates' then the UR source's, packed in emission order
+    into passes of :func:`inject_width` columns, as many as the fullest
+    member allocates (:func:`inject_fired`).
+
     ``probes`` compiles in the sim-plane observation rings
     (:mod:`repro.obs.probes`): per-level link utilization, per-app
     in-flight latency, pool occupancy, and queue depth sampled every
@@ -653,20 +784,15 @@ def build_engine(
         hist_link_level = jnp.asarray(_hl)
         hist_n_levels = max(len(_levels), 1)
 
-    # static candidate-index patterns for the stacked injection pass:
-    # candidates are job-major, rank-major, emission-minor — the same order
-    # the historical per-job loop allocated slots in.
+    # the stacked injection pass's candidates are job-major, rank-major,
+    # emission-minor — the same order the historical per-job loop
+    # allocated slots in: candidate i < N is emission i % MAXE of rank
+    # (i // MAXE) % Pmax of job i // (Pmax * MAXE); candidate N + r is
+    # the UR source's rank r, allocated after every job's, as the
+    # historical UR pass was. Those that fired are routed in passes of
+    # ``cand_width`` (``inject_fired``).
     N = J * Pmax * MAXE
-    cand_job = np.repeat(np.arange(J, dtype=np.int32), Pmax * MAXE)  # (N,)
-    cand_rank = np.tile(
-        np.repeat(np.arange(Pmax, dtype=np.int32), MAXE), J
-    )  # (N,)
-    cand_local = np.tile(
-        np.arange(Pmax * MAXE, dtype=np.uint32), J
-    )  # (N,) p*MAXE+e within each job block
-    cand_job_j = jnp.asarray(cand_job)
-    cand_rank_j = jnp.asarray(cand_rank)
-    cand_local_j = jnp.asarray(cand_local)
+    cand_width = inject_width(N + Pu, M)
 
     # ------------------------------------------------------------------
     # stacked emission: one pass computes this (op, round)'s messages for
@@ -799,78 +925,6 @@ def build_engine(
         return vm, dst, size
 
     # ------------------------------------------------------------------
-    # pool allocation: one flat batch of candidates per member
-    # ------------------------------------------------------------------
-    def inject(pool: PoolState, metrics: Metrics, t, src_ranks, dst_ranks,
-               dsts_node, srcs_node, sizes, app_id, rand, demand,
-               job_of_cand=None):
-        """Allocate + route a flat batch of candidate messages (mask:
-        dst>=0), batched over members.
-
-        All per-candidate args are (B, n); ``rand`` carries the per-job rng
-        schedule so the draw stream matches a per-job sequential injection.
-        ``job_of_cand`` (n,) groups candidates per app for the peak-inject
-        metric (None: the whole call is one app).
-        """
-        B, n = dst_ranks.shape
-        mask = dst_ranks >= 0
-        k = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1  # emission order
-        n_emit = mask.sum(axis=1)  # (B,)
-        can = (k < pool.free_top[:, None]) & mask
-        slot_pos = jnp.clip(pool.free_top[:, None] - 1 - k, 0, M - 1)
-        slot = jnp.take_along_axis(pool.free_stack, slot_pos, axis=1)
-        slot = jnp.where(can, slot, M)  # M = dummy row
-
-        demand_f = demand.reshape(-1)  # (B * (L+1),)
-        offs = jnp.repeat(jnp.arange(B, dtype=jnp.int32) * (L + 1), n)
-        routes, hops = route_fn(
-            T, srcs_node.reshape(-1), dsts_node.reshape(-1),
-            rand.reshape(-1).astype(jnp.int32) & 0x7FFFFFFF,
-            demand_f, adaptive, demand_offsets=offs,
-        )
-        routes = routes.reshape(B, n, -1)
-        hops = hops.reshape(B, n)
-
-        active = _flat_set(pool.active, slot, True, valid=can)
-        src_rank = _flat_set(pool.src_rank, slot, src_ranks, valid=can)
-        dst_rank = _flat_set(pool.dst_rank, slot, dst_ranks, valid=can)
-        job = _flat_set(pool.job, slot, app_id, valid=can)
-        size_a = _flat_set(pool.size, slot, sizes, valid=can)
-        rem = _flat_set(pool.bytes_rem, slot, sizes, valid=can)
-        inj = _flat_set(pool.inject_t, slot, t[:, None], valid=can)
-        mina = _flat_set(
-            pool.min_arrive, slot,
-            t[:, None] + hops.astype(jnp.float32) * net.hop_latency_us,
-            valid=can,
-        )
-        # route rows: scatter whole (K,) rows per slot
-        rts_flat = pool.routes.reshape(B * M, -1)
-        row_idx = slot + (jnp.arange(B, dtype=jnp.int32) * M)[:, None]
-        row_idx = jnp.where(can, row_idx, B * M)
-        rts = rts_flat.at[row_idx.reshape(-1)].set(
-            routes.reshape(B * n, -1), mode="drop"
-        ).reshape(pool.routes.shape)
-
-        n_alloc = jnp.minimum(n_emit, pool.free_top)
-        pool = pool._replace(
-            active=active, src_rank=src_rank, dst_rank=dst_rank, job=job,
-            size=size_a, bytes_rem=rem, inject_t=inj, min_arrive=mina,
-            routes=rts, free_top=pool.free_top - n_alloc,
-            dropped=pool.dropped + (n_emit - n_alloc),
-        )
-        inj_bytes = jnp.where(can, sizes, 0.0)
-        if job_of_cand is not None:
-            # per-app bytes this tick (candidates are job-major blocks)
-            per_job = inj_bytes.reshape(B, J, -1).sum(axis=2)
-            peak = jnp.max(per_job, axis=1)
-        else:
-            peak = inj_bytes.sum(axis=1)
-        metrics = metrics._replace(
-            peak_inject=jnp.maximum(metrics.peak_inject, peak)
-        )
-        return pool, metrics
-
-    # ------------------------------------------------------------------
     # the tick (batched: every leaf carries the member dim B)
     # ------------------------------------------------------------------
     def _n_rounds(opc, a0, a1, P, logp):
@@ -943,43 +997,25 @@ def build_engine(
             base = rng[:, None] + jnp.cumsum(adv, axis=1) - adv  # exclusive
             rng_jobs = rng + jnp.sum(adv, axis=1)
 
-            dst_f = dst.reshape(B, N)
-            sizes_f = jnp.broadcast_to(
-                sizes[:, :, :, None], (B, J, Pmax, MAXE)
-            ).reshape(B, N)
-            r2n_f = jt.r2n.reshape(B, J * Pmax)
-            srcs_node = r2n_f[:, cand_job_j * Pmax + cand_rank_j]
-            dst_node_idx = cand_job_j[None, :] * Pmax + jnp.maximum(dst_f, 0)
-            dsts_node = jnp.take_along_axis(r2n_f, dst_node_idx, axis=1)
-            rand = _hash(base[:, cand_job_j] + cand_local_j[None, :])
-
             ur_state = state.ur
             rng2 = rng_jobs
             injecting = jnp.any(fired, axis=1)  # (B,)
             if ur_state is not None:
                 fire = (t[:, None] >= ur_state.next_t) & live_m[:, None]
-                rnd = _hash(
-                    ur_state.count.astype(jnp.uint32) * jnp.uint32(9781)
-                    + jnp.arange(Pu, dtype=jnp.uint32)[None, :]
-                    + rng_jobs[:, None]
-                )
-                dstn = (rnd % jnp.uint32(n_nodes)).astype(jnp.int32)
-                ur_rand = _hash(
-                    rng_jobs[:, None]
-                    + jnp.arange(Pu, dtype=jnp.uint32)[None, :]
-                )
                 injecting = injecting | jnp.any(fire, axis=1)
             any_inject = jnp.any(injecting)
 
         # injection hides behind one real cond over the whole batch:
         # pure-drain ticks (the majority) skip the demand scatter AND all
-        # route computation. Inside, non-emitting members'/jobs' candidates
-        # are fully masked, so taking the branch for them is a bit-exact
-        # no-op (rng schedules are handled outside via ``fired``).
+        # route computation. Inside, only the candidates that fired are
+        # gathered, routed and written, packed in emission order into
+        # passes of ``cand_width``; a member or job that did not fire adds
+        # nothing
+        # (rng schedules are handled outside via ``fired``).
         def do_inject(args):
             pool, metrics = args
             # link demand (outstanding bytes per link) from the
-            # PRE-injection pool — the job pass and the UR pass both route
+            # PRE-injection pool — the job and the UR candidates all route
             # against this same snapshot (the historical tick-start value).
             valid = (pool.routes >= 0) & pool.active[:, :, None]
             lidx = jnp.where(valid, pool.routes, L)  # dummy L
@@ -994,28 +1030,81 @@ def build_engine(
             demand = demand.at[:, :L].add(
                 jnp.where(eff_f > 0.0, 0.0, 1e18)
             )
+            demand_f = demand.reshape(-1)  # (B * (L+1),)
 
-            pool, metrics = inject(
-                pool, metrics, t,
-                jnp.broadcast_to(cand_rank_j, (B, N)), dst_f,
-                dsts_node, srcs_node, sizes_f,
-                jnp.broadcast_to(cand_job_j, (B, N)), rand, demand,
-                job_of_cand=cand_job_j,
-            )
-            if ur_state is not None:
-                pool, metrics = inject(
-                    pool, metrics, t,
-                    jnp.broadcast_to(jnp.arange(Pu, dtype=jnp.int32), (B, Pu)),
-                    jnp.where(fire, 0, -1),  # dst_rank 0 marker (not tracked)
-                    dstn, state.ur_nodes,
-                    jnp.full((B, Pu), float(ur.size_bytes), jnp.float32),
-                    jnp.full((B, Pu), J, jnp.int32), ur_rand, demand,
+            def route(src_node, dst_node, rand):
+                W = src_node.shape[1]
+                offs = jnp.repeat(jnp.arange(B, dtype=jnp.int32) * (L + 1), W)
+                routes, hops = route_fn(
+                    T, src_node.reshape(-1), dst_node.reshape(-1),
+                    rand.reshape(-1).astype(jnp.int32) & 0x7FFFFFFF,
+                    demand_f, adaptive, demand_offsets=offs,
                 )
-            return pool, metrics
+                return routes.reshape(B, W, -1), hops.reshape(B, W)
+
+            dst_f = dst.reshape(B, N)
+            r2n_f = jt.r2n.reshape(B, J * Pmax)
+            mask = dst_f >= 0
+            if ur_state is not None:
+                mask = jnp.concatenate([mask, fire], axis=1)
+
+            def cands(idx):
+                at = jnp.minimum(idx, N - 1)
+                jr = at // MAXE  # job * Pmax + rank
+                job = jr // Pmax
+                dst_rank = jnp.take_along_axis(dst_f, at, axis=1)
+                c = Cands(
+                    src_rank=jr % Pmax, dst_rank=dst_rank,
+                    src_node=jnp.take_along_axis(r2n_f, jr, axis=1),
+                    dst_node=jnp.take_along_axis(
+                        r2n_f, job * Pmax + jnp.maximum(dst_rank, 0), axis=1),
+                    size=jnp.take_along_axis(
+                        sizes.reshape(B, J * Pmax), jr, axis=1),
+                    app=job,
+                    # the per-job rng schedule: job j's candidate p*MAXE+e
+                    # draws from base[j] + p*MAXE + e
+                    rand=_hash(jnp.take_along_axis(base, job, axis=1)
+                               + (at % (Pmax * MAXE)).astype(jnp.uint32)),
+                )
+                if ur_state is None:
+                    return c
+                r = jnp.clip(idx - N, 0, Pu - 1)  # the UR source's rank
+                ru = r.astype(jnp.uint32)
+                rnd = _hash(
+                    jnp.take_along_axis(ur_state.count, r, axis=1)
+                    .astype(jnp.uint32) * jnp.uint32(9781)
+                    + ru + rng_jobs[:, None]
+                )
+                u = Cands(
+                    src_rank=r,
+                    dst_rank=jnp.zeros_like(r),  # not tracked
+                    src_node=jnp.take_along_axis(state.ur_nodes, r, axis=1),
+                    dst_node=(rnd % jnp.uint32(n_nodes)).astype(jnp.int32),
+                    size=jnp.full(r.shape, float(ur.size_bytes), jnp.float32),
+                    app=jnp.full(r.shape, J, jnp.int32),
+                    rand=_hash(rng_jobs[:, None] + ru),
+                )
+                return Cands(*(jnp.where(idx >= N, b, a)
+                               for a, b in zip(c, u)))
+
+            pool, can, passes = inject_fired(
+                pool, t, mask, cand_width, cands, route, net.hop_latency_us)
+            # per-app bytes this tick (job candidates are job-major
+            # blocks; the UR source is one app)
+            inj_bytes = jnp.where(can[:, :N], jnp.broadcast_to(
+                sizes[:, :, :, None], (B, J, Pmax, MAXE)).reshape(B, N), 0.0)
+            peak = jnp.max(inj_bytes.reshape(B, J, -1).sum(axis=2), axis=1)
+            if ur_state is not None:
+                peak = jnp.maximum(peak, jnp.where(
+                    can[:, N:], jnp.float32(ur.size_bytes), 0.0).sum(axis=1))
+            metrics = metrics._replace(
+                peak_inject=jnp.maximum(metrics.peak_inject, peak))
+            return pool, metrics, passes
 
         with jax.named_scope("tick/inject"):
-            pool, metrics = jax.lax.cond(
-                any_inject, do_inject, lambda a: a, (pool, metrics)
+            pool, metrics, inject_passes = jax.lax.cond(
+                any_inject, do_inject, lambda a: a + (jnp.int32(0),),
+                (pool, metrics)
             )
         drained_active = pool.active  # what the drain pass sees
 
@@ -1228,11 +1317,11 @@ def build_engine(
         # --- 8. always-on tick counters (per member, live ticks only) ---
         with jax.named_scope("tick/counters"):
             live_i = live_m.astype(jnp.int32)
+            inject_i = (injecting & live_m).astype(jnp.int32)
             n_active = jnp.sum(drained_active, axis=1, dtype=jnp.int32)
             metrics = metrics._replace(
                 ticks=metrics.ticks + live_i,
-                inject_ticks=metrics.inject_ticks
-                + (injecting & live_m).astype(jnp.int32),
+                inject_ticks=metrics.inject_ticks + inject_i,
                 active_slot_ticks=metrics.active_slot_ticks
                 + (n_active * live_i).astype(jnp.float32),
                 peak_active=jnp.maximum(
@@ -1240,6 +1329,8 @@ def build_engine(
                 drain_rung_ticks=metrics.drain_rung_ticks
                 + (jnp.arange(n_rungs) == drain_rung).astype(jnp.int32)
                 * live_i[:, None],
+                inject_passes=metrics.inject_passes
+                + inject_passes * inject_i,
             )
 
         # --- 9. sim-plane probes (compiled in only when requested) ---
@@ -1357,6 +1448,7 @@ def build_engine(
             active_slot_ticks=jnp.float32(0.0),
             peak_active=jnp.int32(0),
             drain_rung_ticks=jnp.zeros((n_rungs,), jnp.int32),
+            inject_passes=jnp.int32(0),
         )
         if faults is None:
             flt = FaultState(

@@ -30,8 +30,10 @@ from repro.netsim.engine import (
     JobSpec,
     URSpec,
     get_engine,
+    inject_candidates,
     job_vm,
     member_state,
+    routed_candidates,
 )
 from repro.netsim.placement import place_jobs
 from repro.netsim.topology import Fabric, get_topology
@@ -232,14 +234,18 @@ def member_report(state, rs: ResolvedScenario, wall_s: float = 0.0,
         (m.ticks, m.inject_ticks, m.active_slot_ticks, m.peak_active,
          m.drain_rung_ticks))
     ticks, slots = int(ticks), int(state.pool.active.shape[-1])
+    inject_ticks, cands = int(inject_ticks), inject_candidates(state)
     rep["engine"] = dict(
-        ticks=ticks, inject_ticks=int(inject_ticks),
+        ticks=ticks, inject_ticks=inject_ticks,
         mean_pool_occupancy=(float(active) / (ticks * slots)
                              if ticks else 0.0),
         peak_pool_occupancy=int(peak) / slots,
         mean_drain_width=(
             int(np.dot(rungs, np.asarray(drain_widths(slots), np.int64)))
             / (ticks * slots) if ticks else 0.0),
+        mean_inject_width=(
+            float(routed_candidates(state)[0]) / (inject_ticks * cands)
+            if inject_ticks else 0.0),
     )
     if getattr(state, "probes", None) is not None:
         from repro.obs import probe_timelines

@@ -38,7 +38,8 @@ import numpy as np
 # not capture (a changed store version invalidates every prior entry).
 # 2: member reports carry the engine's tick counters (``report["engine"]``).
 # 3: ``report["engine"]`` carries the drain's ``mean_drain_width``.
-STORE_VERSION = 3
+# 4: ``report["engine"]`` carries the injection's ``mean_inject_width``.
+STORE_VERSION = 4
 
 _VERSIONS: Optional[Dict[str, Any]] = None
 

@@ -7,6 +7,9 @@ host-plane tracing that reads them.
 * ``Metrics.drain_rung_ticks`` counts each member's live ticks at the
   drain width it took, as a host replay of the chosen widths does, and
   ``report["engine"]["mean_drain_width"]`` reads it;
+* ``Metrics.inject_passes`` counts the passes each member's injecting
+  ticks routed their candidates in, as a host replay of the fired
+  counts does, and ``report["engine"]["mean_inject_width"]`` reads it;
 * ``Engine.phase_map`` names every ``tick/<phase>`` scope;
 * spans mirror onto a ``jax.profiler`` trace's host plane only while
   tracing, and the facade emits ``engine.counters`` only then.
@@ -22,12 +25,17 @@ import jax
 
 from repro import obs, union
 from repro.kernels.ops import drain_widths
-from repro.netsim.engine import hlo_phases, member_state, stack_members
+from repro.netsim import engine as ENG
+from repro.netsim.engine import (
+    MAXE, hlo_phases, inject_width, member_state, routed_candidates,
+    stack_members,
+)
 from repro.obs import ProbeConfig
 from repro.union import manager as MGR
 from repro.union.scenario import Scenario, ScenarioJob, URDecl
 
 import test_engine_equivalence as EQ
+import test_inject_live as INJ
 import test_obs as OBS
 
 HORIZON_US = EQ.mixed_scenario().horizon_ms * 1000.0
@@ -162,6 +170,68 @@ def test_mean_drain_width_equals_a_host_replay():
         assert blk["mean_pool_occupancy"] <= blk["mean_drain_width"] < 1.0
 
 
+def test_inject_passes_count_injecting_ticks(mixed):
+    """A member's injecting ticks each take a pass or more, and only they
+    take any (the mix drops nothing, so each one allocates)."""
+    eng, state = mixed
+    m = jax.device_get(eng.run(state)).metrics
+    assert (m.inject_ticks > 0).all()
+    assert (m.inject_passes >= m.inject_ticks).all()
+    assert (m.inject_passes < 2 * m.inject_ticks).all()
+
+
+@pytest.mark.parametrize("width", [1024, 256])
+def test_mean_inject_width_equals_a_host_replay(monkeypatch, width):
+    """The bursty mix of ``test_inject_live``: 216 ranks fire 1,296, 864
+    and up to 216 candidates a tick, with 64 UR candidates, so passes of
+    1,024 route a tick in one or two and passes of 256 in one to six."""
+    monkeypatch.setattr(ENG, "INJECT_WIDTH", width)
+    rs = MGR.resolve(INJ.bursty_scenario(), seed=3)
+    eng = INJ.uncached_engine(rs)
+    state = stack_members([eng.init_state(seed=1),
+                           eng.init_state(seed=2, start_us=[90.0, 0.0])])
+    n = 2 * 256 * MAXE + 64
+    assert inject_width(n, 4096) == width
+    tick = jax.jit(eng.tick)
+    s = jax.device_get(state)
+    passes = np.zeros(2, np.int64)
+    taken = set()
+    horizon = rs.scenario.horizon_ms * 1000.0
+    while True:
+        done = s.vms.done.all(axis=(1, 2)) & ~s.pool.active.any(axis=1)
+        live = (s.t < horizon) & ~done
+        if not live.any():
+            break
+        nxt = jax.device_get(tick(s))
+        # a job candidate fires for each message a rank enters its
+        # (op, round) with, so the fired count is what the ranks' send
+        # needs grew by, and a UR rank's count grows when it fires; the
+        # rng moves by more than 1 when a job or the UR source fired. The
+        # batch takes as many passes as its fullest member allocates.
+        fired = (nxt.vms.send_need.sum(axis=(1, 2))
+                 - s.vms.send_need.sum(axis=(1, 2))
+                 + nxt.ur.count.sum(axis=1) - s.ur.count.sum(axis=1))
+        drng = (nxt.rng.astype(np.int64) - s.rng.astype(np.int64)) % 2**32
+        injecting = live & (drng > 1)
+        k = -(-np.minimum(fired, s.pool.free_top).max() // width)
+        passes += injecting * k
+        if injecting.any():
+            taken.add(int(k))
+        s = nxt
+    final = jax.device_get(eng.run(state))
+    np.testing.assert_array_equal(final.metrics.inject_passes, passes)
+    assert len(taken) >= (2 if width == 1024 else 3)
+    routed = passes * width
+    np.testing.assert_array_equal(routed_candidates(final), routed)
+    for b in range(2):
+        blk = MGR.member_report(member_state(final, b), rs)["engine"]
+        ticks = int(final.metrics.inject_ticks[b])
+        assert blk["inject_ticks"] == ticks
+        assert blk["mean_inject_width"] == pytest.approx(
+            routed[b] / (ticks * n), rel=1e-12)
+        assert 0.0 < blk["mean_inject_width"] < 1.0
+
+
 def test_phase_map_names_every_phase():
     """A probed engine with a UR source compiles every phase; each mapped
     instruction carries a ``tick/`` scope or feeds one."""
@@ -287,6 +357,15 @@ def test_engine_counters_event_only_with_spans(tracer, vmapped):
     assert sum(e["drain_slot_ticks"] for e in evs) == round(sum(
         c.report["engine"]["mean_drain_width"] * c.report["engine"]["ticks"]
         * M for c in res.cells))
+    # the injection's candidate-ticks: Σ injecting ticks x width, against
+    # a member's full candidate width
+    assert all(e["inject_cand_ticks"] == int(e["inject_cand_ticks"]) > 0
+               for e in evs)
+    assert all(e["inject_cand_width"] == 2 * 2 * 8 for e in evs)
+    assert sum(e["inject_cand_ticks"] for e in evs) == round(sum(
+        c.report["engine"]["mean_inject_width"]
+        * c.report["engine"]["inject_ticks"] * 2 * 2 * 8
+        for c in res.cells))
     names = {e["name"] for e in tr.events}
     assert {"planner.resolve", "engine.report"} <= names
     pm = obs.device_phases()
