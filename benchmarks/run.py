@@ -177,37 +177,8 @@ def bench_engine_tick():
     return True
 
 
-def bench_kernel_router():
-    from repro.kernels import ops
-
-    key = jax.random.PRNGKey(0)
-    M, L = 8192, 1500
-    routes = jax.random.randint(key, (M, 10), -1, L)
-    rem = jax.random.uniform(jax.random.fold_in(key, 1), (M,)) * 1e5
-    act = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.7, (M,))
-    share = jax.random.uniform(jax.random.fold_in(key, 3), (L,)) * 1e3
-
-    f = lambda: jax.block_until_ready(
-        ops.router_rate_drain(routes, rem, act, share, 1.0, use_pallas=False))
-    f()
-    t0 = time.time()
-    for _ in range(50):
-        f()
-    us = (time.time() - t0) / 50 * 1e6
-    g = lambda: jax.block_until_ready(
-        ops.router_rate_drain(routes, rem, act, share, 1.0, use_pallas=True))
-    g()
-    t0 = time.time()
-    for _ in range(3):
-        g()
-    us_p = (time.time() - t0) / 3 * 1e6
-    _emit("kernel_router_jnp", us, f"M={M}")
-    _emit("kernel_router_pallas_interpret", us_p, "correctness_path_only")
-    return True
-
-
 def bench_kernel_ssd():
-    from repro.kernels import ops
+    from repro.kernels.ssd_scan import ssd_scan
 
     key = jax.random.PRNGKey(1)
     BH, nc, Q, hd, ds = 16, 8, 128, 64, 64
@@ -216,7 +187,7 @@ def bench_kernel_ssd():
     A = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 2), (BH,)))
     Bm = jax.random.normal(jax.random.fold_in(key, 3), (BH, nc, Q, ds))
     Cm = jax.random.normal(jax.random.fold_in(key, 4), (BH, nc, Q, ds))
-    f = lambda: jax.block_until_ready(ops.ssd_scan(x, dt, A, Bm, Cm, use_pallas=False))
+    f = lambda: jax.block_until_ready(ssd_scan(x, dt, A, Bm, Cm, use_pallas=False))
     f()
     t0 = time.time()
     for _ in range(10):
@@ -267,7 +238,6 @@ def main() -> None:
         bench_fig9_commtime,
         bench_table6_linkload,
         bench_engine_tick,
-        bench_kernel_router,
         bench_kernel_ssd,
         bench_roofline_table,
     ):

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# ops.py: the engine's drain tick and its packing helpers (jnp).
+# ssd_scan.py: the LM stack's Pallas SSD scan with its jnp oracle; the
+# simulator does not import it.

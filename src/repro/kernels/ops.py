@@ -1,9 +1,9 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""The engine's drain tick (engine steps 2-3), in jnp, on every backend.
 
-``use_pallas`` selects the kernel path (interpret mode on CPU). The
-engine uses the pure-jnp drain on every backend — Mosaic cannot lower
-the drain kernel (``kernels/drain_tick.py``) — and these wrappers are
-exercised by the per-kernel allclose sweeps in tests/.
+:func:`drain_tick` is the fluid fair-share drain over an explicit member
+batch: link demand (messages per link) -> fair-share rate -> per-message
+drain -> delivery mask, plus the per-link and per-(app, router) byte
+counters the paper's router windows need.
 
 The engine drains through :func:`drain_tick_live`: each tick it packs
 the pool's live slots, in slot order, into the smallest width of a
@@ -21,65 +21,70 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
-from repro.kernels.drain_tick import drain_tick_pallas
-from repro.kernels.drain_tick import BLOCK_M as DRAIN_BLOCK_M
-from repro.kernels.router_tick import BLOCK_M, router_rate_drain_pallas
-from repro.kernels.ssd_scan import ssd_scan_pallas
 
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def router_rate_drain(routes, bytes_rem, active, share, dt,
-                      use_pallas: bool = False, interpret: bool = True):
-    if not use_pallas:
-        return ref.router_rate_drain_ref(routes, bytes_rem, active, share, dt)
-    M = routes.shape[0]
-    pad = (-M) % BLOCK_M
-    if pad:
-        routes = jnp.pad(routes, ((0, pad), (0, 0)), constant_values=-1)
-        bytes_rem = jnp.pad(bytes_rem, (0, pad))
-        active = jnp.pad(active, (0, pad))
-    new_rem, rate, drained = router_rate_drain_pallas(
-        routes, bytes_rem, active, share, dt, interpret=interpret
-    )
-    return new_rem[:M], rate[:M], drained[:M]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_apps", "n_routers", "use_pallas", "interpret"),
-)
+@functools.partial(jax.jit, static_argnames=("n_apps", "n_routers"))
 def drain_tick(routes, bytes_rem, active, job, min_arrive, t, dt, bw_eff,
-               link_dst_router, *, n_apps: int, n_routers: int,
-               use_pallas: bool = False, interpret: bool = True):
-    """Fused drain tick (engine steps 2-3) over an explicit member batch.
+               link_dst_router, *, n_apps: int, n_routers: int):
+    """One drain tick over every member x message of a batch.
 
-    See `ref.drain_tick_ref` for shapes/semantics — ``bw_eff`` is
-    ``(L+1,)`` or per-member ``(B, L+1)`` (runtime fault factors). The
-    jnp path is the engine's drain on every backend: its scatters fold
-    the member index into one flat index, which is what fixes the
-    vmapped-campaign regression.
+    All scatters fold the member index into a single flat index, so XLA
+    emits one scatter for the batch, not a serialized scatter a member.
+
+    routes: (B, M, K) int32 link ids (-1 pad); bytes_rem: (B, M) f32;
+    active: (B, M) bool; job: (B, M) int32 app ids (< n_apps);
+    min_arrive: (B, M) f32; t: (B,) f32; dt: scalar f32;
+    bw_eff: (L+1,) or (B, L+1) f32 effective per-link bandwidth (0 for
+    failed links, dummy last) — per-**member** rows let one compiled
+    engine drain an ensemble of different failure patterns
+    (repro.netsim.faults); a 1-D vector broadcasts to every member;
+    link_dst_router: (L+1,) int32 destination router per link (dummy last).
+
+    A message's rate is the least fair share (bandwidth ÷ messages, in
+    bytes/us) over its route's links, 0 for an inactive or routeless
+    message; it drains ``min(rate * dt, bytes_rem)`` and is delivered
+    once active, with at most 1e-6 bytes left, at ``t >= min_arrive``.
+
+    Returns (new_rem (B,M), rate (B,M), delivered (B,M) bool,
+             link_bytes_delta (B, L+1), router_win_delta (B, n_apps, R)).
     """
-    if not use_pallas:
-        return ref.drain_tick_ref(
-            routes, bytes_rem, active, job, min_arrive, t, dt, bw_eff,
-            link_dst_router, n_apps, n_routers,
-        )
     B, M, K = routes.shape
-    if bw_eff.ndim == 1:
-        bw_eff = jnp.broadcast_to(bw_eff, (B, bw_eff.shape[0]))
-    pad = (-M) % DRAIN_BLOCK_M
-    if pad:
-        routes = jnp.pad(routes, ((0, 0), (0, pad), (0, 0)), constant_values=-1)
-        bytes_rem = jnp.pad(bytes_rem, ((0, 0), (0, pad)))
-        active = jnp.pad(active, ((0, 0), (0, pad)))
-        job = jnp.pad(job, ((0, 0), (0, pad)))
-        min_arrive = jnp.pad(min_arrive, ((0, 0), (0, pad)))
-    new_rem, rate, delivered, lb, rw = drain_tick_pallas(
-        routes, bytes_rem, active, job, min_arrive, t, dt, bw_eff,
-        link_dst_router, n_apps, n_routers, interpret=interpret,
+    Lp = bw_eff.shape[-1]
+    valid = (routes >= 0) & active[:, :, None]
+    lidx = jnp.where(valid, routes, Lp - 1)
+    boff = (jnp.arange(B, dtype=jnp.int32) * Lp)[:, None, None]
+    flat = (lidx + boff).reshape(-1)
+
+    n_l = (
+        jnp.zeros((B * Lp,), jnp.float32)
+        .at[flat].add(valid.reshape(-1).astype(jnp.float32))
     )
-    return new_rem[:, :M], rate[:, :M], delivered[:, :M], lb, rw
+    bw2 = jnp.broadcast_to(bw_eff, (B, Lp))
+    share = bw2 / jnp.maximum(n_l.reshape(B, Lp), 1.0) * 1e-6
+    per_link = jnp.where(valid, share.reshape(-1)[flat].reshape(B, M, K), jnp.inf)
+    rate = jnp.min(per_link, axis=2)
+    rate = jnp.where(active & jnp.isfinite(rate), rate, 0.0)
+    drain = jnp.minimum(rate * dt, bytes_rem)
+    new_rem = bytes_rem - drain
+
+    drain_b = jnp.where(valid, drain[:, :, None], 0.0)
+    link_bytes_delta = (
+        jnp.zeros((B * Lp,), jnp.float32)
+        .at[flat].add(drain_b.reshape(-1))
+        .reshape(B, Lp)
+    )
+    rtr = link_dst_router[lidx]  # (B, M, K)
+    appidx = jnp.broadcast_to(job[:, :, None], lidx.shape)
+    rw_flat = (
+        appidx * n_routers + rtr
+        + (jnp.arange(B, dtype=jnp.int32) * n_apps * n_routers)[:, None, None]
+    )
+    router_win_delta = (
+        jnp.zeros((B * n_apps * n_routers,), jnp.float32)
+        .at[rw_flat.reshape(-1)].add(drain_b.reshape(-1))
+        .reshape(B, n_apps, n_routers)
+    )
+    delivered = active & (new_rem <= 1e-6) & (t[:, None] >= min_arrive)
+    return new_rem, rate, delivered, link_bytes_delta, router_win_delta
 
 
 # the drain's ladder of widths: the pool size, then halvings down to a
@@ -110,13 +115,9 @@ def pack_columns(mask, col, W: int):
             .reshape(B, W))
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_apps", "n_routers", "use_pallas", "interpret"),
-)
+@functools.partial(jax.jit, static_argnames=("n_apps", "n_routers"))
 def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
-                    bw_eff, link_dst_router, *, n_apps: int, n_routers: int,
-                    use_pallas: bool = False, interpret: bool = True):
+                    bw_eff, link_dst_router, *, n_apps: int, n_routers: int):
     """:func:`drain_tick` over the live pool slots only.
 
     Picks the smallest width ``W`` of ``drain_widths(M)`` that holds the
@@ -139,7 +140,7 @@ def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
     drain = functools.partial(
         drain_tick, t=t, dt=dt, bw_eff=bw_eff,
         link_dst_router=link_dst_router, n_apps=n_apps,
-        n_routers=n_routers, use_pallas=use_pallas, interpret=interpret)
+        n_routers=n_routers)
 
     def packed(W):
         # live slot m of member b goes to column cumsum(active)[b, m] - 1
@@ -173,15 +174,3 @@ def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
     branches = [lambda _: drain(routes, bytes_rem, active, job, min_arrive)]
     branches += [(lambda _, W=W: packed(W)) for W in widths[1:]]
     return jax.lax.switch(rung, branches, None) + (rung,)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def ssd_scan(x, dt, A, Bm, Cm, use_pallas: bool = False, interpret: bool = True):
-    """Head-flattened SSD scan: see ssd_scan_pallas for shapes."""
-    if not use_pallas:
-        y, h = jax.vmap(ref.ssd_chunk_ref)(
-            x, dt, A, Bm, Cm,
-            jnp.zeros((x.shape[0], Bm.shape[-1], x.shape[-1]), jnp.float32),
-        )
-        return y, h
-    return ssd_scan_pallas(x, dt, A, Bm, Cm, interpret=interpret)

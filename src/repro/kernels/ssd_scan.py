@@ -12,8 +12,9 @@ with Q×Q and ds×hd matmuls on the MXU. Block shapes: x (Q, hd), B/C
 ~0.4 MiB « VMEM. B/C blocks are shared across heads (index_map drops the
 head coordinate).
 
-Oracle: `ref.ssd_chunk_ref`; the model's jnp path (models/mamba2.py) is the
-production fallback on non-TPU backends.
+Oracle: :func:`ssd_chunk_ref`; :func:`ssd_scan` runs either. The model's
+jnp path (models/mamba2.py) is the production fallback on non-TPU
+backends.
 """
 from __future__ import annotations
 
@@ -89,3 +90,49 @@ def ssd_scan_pallas(x, dt, A, Bm, Cm, *, interpret: bool = True):
         interpret=interpret,
     )(x, dt, A, Bm, Cm)
     return y, h
+
+
+def ssd_chunk_ref(x, dt, A, Bm, Cm, h0):
+    """Reference for one head's SSD over all chunks (sequential).
+
+    x: (nc, Q, hd) f32 — pre-multiplied by nothing (raw inputs)
+    dt: (nc, Q) f32, A: scalar (negative), Bm/Cm: (nc, Q, ds) f32
+    h0: (ds, hd) initial state.
+    Returns (y (nc, Q, hd), h_final (ds, hd)).
+    """
+    nc, Q, hd = x.shape
+    ds = Bm.shape[-1]
+
+    def chunk(h, inp):
+        xc, dtc, Bc, Cc = inp
+        dA = dtc * A  # (Q,)
+        cs = jnp.cumsum(dA)
+        seg = jnp.exp(cs[-1])
+        L = jnp.where(
+            jnp.tril(jnp.ones((Q, Q), bool)),
+            jnp.exp(cs[:, None] - cs[None, :]),
+            0.0,
+        )
+        CB = Cc @ Bc.T  # (Q, Q)
+        xdt = xc * dtc[:, None]
+        y_intra = (CB * L) @ xdt
+        decay_in = jnp.exp(cs)[:, None]
+        y_inter = (Cc @ h) * decay_in
+        decay_out = jnp.exp(cs[-1] - cs)[:, None]
+        h_new = h * seg + Bc.T @ (xdt * decay_out)
+        return h_new, y_intra + y_inter
+
+    h, y = jax.lax.scan(chunk, h0, (x, dt, Bm, Cm))
+    return y, h
+
+
+@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+def ssd_scan(x, dt, A, Bm, Cm, use_pallas: bool = False, interpret: bool = True):
+    """Head-flattened SSD scan: see ssd_scan_pallas for shapes."""
+    if not use_pallas:
+        y, h = jax.vmap(ssd_chunk_ref)(
+            x, dt, A, Bm, Cm,
+            jnp.zeros((x.shape[0], Bm.shape[-1], x.shape[-1]), jnp.float32),
+        )
+        return y, h
+    return ssd_scan_pallas(x, dt, A, Bm, Cm, interpret=interpret)

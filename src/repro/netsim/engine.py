@@ -9,7 +9,7 @@ One `tick` advances Δt of virtual time:
   2. **Injection**: emitted messages get pool slots (stack allocator),
      routes (MIN or adaptive, live link demand) and latency floors.
   3. **Network**: fluid fair-share wormhole model — the fused drain tick
-     (`kernels/drain_tick.py`): link demand → fair-share rate →
+     (`kernels.ops.drain_tick`): link demand → fair-share rate →
      per-message drain → delivery mask in one pass, over the live pool
      slots packed into the smallest of M, M/2, ..., M/16 slots that holds
      them (`kernels.ops.drain_tick_live`).
@@ -155,9 +155,9 @@ class Metrics(NamedTuple):
     # (n_rungs,) int32 live ticks drained at each width of
     # ``kernels.ops.drain_widths(M)`` (widest, the whole pool, first)
     drain_rung_ticks: jnp.ndarray
-    # int32 passes of ``inject_width(inject_candidates(state), M)``
-    # candidates routed over the injecting live ticks (a tick takes as
-    # many as its fullest member allocates)
+    # int32 passes of ``inject_width`` candidates routed over the
+    # injecting live ticks (a tick takes as many as its fullest member
+    # allocates); :func:`tick_counts` decodes this and the rung ticks
     inject_passes: jnp.ndarray
 
 
@@ -422,21 +422,48 @@ def inject_width(n_cands: int, M: int) -> int:
     return min(n_cands, M, INJECT_WIDTH)
 
 
-def inject_candidates(state) -> int:
-    """A member's injection candidates a tick, ``Jmax * Pmax * MAXE`` job
-    candidates and the UR source's ``Pu``, from a member or batched
-    state's shapes."""
+class TickCounts(NamedTuple):
+    """Each member's tick counters, decoded by :func:`tick_counts`:
+    float64 ``(n,)`` arrays, flat over a state's leading axes, and the
+    candidates a member has a tick."""
+
+    ticks: np.ndarray  # live ticks
+    inject_ticks: np.ndarray  # live ticks with a candidate or UR fire
+    active_slot_ticks: np.ndarray  # pool slots the drain saw, summed
+    drain_slot_ticks: np.ndarray  # Σ over the drain's rungs of ticks x width
+    inject_cand_ticks: np.ndarray  # candidates routed: passes x width
+    # a tick's candidates: Jmax * Pmax * MAXE job ones and the UR
+    # source's Pu
+    inject_cand_width: int
+
+    def since(self, before: "TickCounts") -> "TickCounts":
+        """The counts taken after ``before`` was read."""
+        return self._replace(**{f: getattr(self, f) - getattr(before, f)
+                                for f in self._fields
+                                if f != "inject_cand_width"})
+
+
+def tick_counts(state) -> TickCounts:
+    """Decode a member or batched state's ``Metrics`` counters: the
+    drain's rung ticks into slot-ticks (exact, in int64) and the
+    injection's passes into candidate-ticks."""
+    m = state.metrics
+    ticks, inject, active, rungs, passes = jax.device_get(
+        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.drain_rung_ticks,
+         m.inject_passes))
+    M = state.pool.active.shape[-1]
+    widths = np.asarray(KOPS.drain_widths(M), np.int64)
     J, Pmax = state.vms.pc.shape[-2:]
     Pu = 0 if state.ur is None else state.ur.next_t.shape[-1]
-    return int(J) * int(Pmax) * MAXE + int(Pu)
+    cands = int(J) * int(Pmax) * MAXE + int(Pu)
 
+    def flat(x):
+        return np.asarray(x, np.float64).reshape(-1)
 
-def routed_candidates(state) -> np.ndarray:
-    """Each member's candidates routed over its injecting ticks (passes x
-    width), float64, flat over any leading axes."""
-    W = inject_width(inject_candidates(state), state.pool.active.shape[-1])
-    passes = jax.device_get(state.metrics.inject_passes)
-    return np.asarray(passes, np.float64).reshape(-1) * W
+    return TickCounts(
+        flat(ticks), flat(inject), flat(active),
+        flat(np.asarray(rungs, np.int64).reshape(-1, widths.size) @ widths),
+        flat(passes) * inject_width(cands, M), cands)
 
 
 class Cands(NamedTuple):
@@ -658,7 +685,6 @@ def build_engine(
     rank_slowdown: Optional[Sequence[np.ndarray]] = None,  # per job (P,) f32
     job_start_us: Optional[Sequence[float]] = None,  # per job arrival offsets
     capacity: Optional[EngineCapacity] = None,
-    use_pallas: bool = False,
     probes: Optional[ProbeConfig] = None,
     hist: Optional[HistConfig] = None,
 ):
@@ -686,13 +712,10 @@ def build_engine(
     jobs[ji].start_us)`` of virtual time — dynamic co-scheduling, where a
     job lands on a network already carrying traffic.
 
-    The drain tick is the jnp fused path (``kernels/ref.drain_tick_ref``)
-    on every backend, run each tick over the live pool slots at the
-    smallest width of ``kernels.ops.drain_widths(M)`` that holds them
-    (the top rung, ``M``, is the full-pool pass). ``use_pallas=True``
-    opts into the Pallas kernel: interpret mode off-TPU (the CPU
-    tests), Mosaic on a TPU, where jax 0.9.0 refuses its in-kernel
-    scatter-add (``kernels/drain_tick.py``).
+    The drain tick (``kernels.ops.drain_tick``) runs each tick over the
+    live pool slots at the smallest width of
+    ``kernels.ops.drain_widths(M)`` that holds them (the top rung, ``M``,
+    is the full-pool pass).
 
     Injecting ticks route and allocate only the candidates that fired,
     the job candidates' then the UR source's, packed in emission order
@@ -722,9 +745,6 @@ def build_engine(
     W = net.max_windows
     R = topo.n_routers
     n_rungs = len(KOPS.drain_widths(M))
-    # an opted-in Pallas drain compiles Mosaic on a TPU and is emulated
-    # in interpret mode elsewhere
-    kernel_interpret = jax.default_backend() != "tpu"
 
     default_table = pack_jobs(
         jobs, cap, job_start_us=job_start_us, rank_slowdown=rank_slowdown
@@ -1121,7 +1141,7 @@ def build_engine(
                 )
 
         # --- 2-3. fused drain tick: demand -> fair share -> drain ->
-        # delivery, plus per-link byte counters (kernels/drain_tick.py),
+        # delivery, plus per-link byte counters (kernels.ops.drain_tick),
         # over the live slots only: packed into the smallest rung of
         # M, M/2, ..., M/16 that holds every member's live slots (the top
         # rung is the full-pool pass), results put back per slot ---
@@ -1130,8 +1150,7 @@ def build_engine(
              drain_rung) = KOPS.drain_tick_live(
                 pool.routes, pool.bytes_rem, pool.active, pool.job,
                 pool.min_arrive, t, jnp.float32(dt), bw_run, link_dstr,
-                n_apps=n_apps, n_routers=R, use_pallas=use_pallas,
-                interpret=kernel_interpret,
+                n_apps=n_apps, n_routers=R,
             )
             # horizon-frozen members may still carry in-flight messages:
             # their drain results are discarded (the freeze in place of a
@@ -1642,7 +1661,6 @@ def engine_cache_key(
     pool_size: Optional[int] = None,
     horizon_us: float = 500_000.0,
     capacity: EngineCapacity,
-    use_pallas: bool = False,
     probes: Optional[ProbeConfig] = None,
     hist: Optional[HistConfig] = None,
 ) -> Tuple:
@@ -1669,7 +1687,7 @@ def engine_cache_key(
     return (
         fabric_key(topo), routing.upper() in ("ADP", "ADAPTIVE"), ur_key,
         net, int(pool_size or net.pool_size), float(horizon_us), capacity,
-        use_pallas, probes, hist,
+        probes, hist,
     )
 
 
@@ -1682,7 +1700,6 @@ def get_engine(
     pool_size: Optional[int] = None,
     horizon_us: float = 500_000.0,
     capacity: EngineCapacity,
-    use_pallas: bool = False,
     probes: Optional[ProbeConfig] = None,
     hist: Optional[HistConfig] = None,
 ) -> Engine:
@@ -1698,8 +1715,7 @@ def get_engine(
     """
     key = engine_cache_key(
         topo, routing=routing, ur=ur, net=net, pool_size=pool_size,
-        horizon_us=horizon_us, capacity=capacity,
-        use_pallas=use_pallas, probes=probes, hist=hist,
+        horizon_us=horizon_us, capacity=capacity, probes=probes, hist=hist,
     )
     eng = _ENGINE_CACHE.get(key)
     if eng is not None:
@@ -1710,8 +1726,7 @@ def get_engine(
     _ENGINE_CACHE_STATS["builds"] += 1
     eng = build_engine(
         topo, [], routing=routing, ur=ur, net=net, pool_size=pool_size,
-        horizon_us=horizon_us, capacity=capacity,
-        use_pallas=use_pallas, probes=probes, hist=hist,
+        horizon_us=horizon_us, capacity=capacity, probes=probes, hist=hist,
     )
     _ENGINE_CACHE[key] = eng
     _evict_to_limit()

@@ -6,12 +6,11 @@ compiler then refuses what the chip would refuse — an op Mosaic cannot
 lower, a program that does not fit the device — at no chip time.
 Nothing runs, so a compile that passes says nothing about results or
 times. ``tests/test_tpu_compile.py`` keeps small cases of these compiles
-in the suite (among them the Mosaic refusal of the Pallas drain
-kernel); this module's ``__main__`` rehearses the paper's widths::
+in the suite; this module's ``__main__`` rehearses the paper's widths::
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.netsim.rehearse
 
-It compiles, for one chip of a v5e:2x2, the jnp drain tick at the 2-D
+It compiles, for one chip of a v5e:2x2, the drain tick at the 2-D
 dragonfly's widths, then ``engine.run`` and ``run_window`` for the
 paper's workload1 on the 1-D dragonfly; and for all four chips, the
 member-sharded run of 8 such members, 2 a chip (about four minutes on a
@@ -58,11 +57,9 @@ def abstract(tree, sharding):
         tree)
 
 
-def compile_drain(sharding, fabric, *, pool_size: int = 65536,
-                  use_pallas: bool = False):
+def compile_drain(sharding, fabric, *, pool_size: int = 65536):
     """The fused drain tick (``kernels.ops.drain_tick``) of one member at
-    ``fabric``'s link and router counts; ``use_pallas`` compiles the
-    Mosaic kernel."""
+    ``fabric``'s link and router counts."""
     B, M, K = 1, pool_size, fabric.route_width
     Lp = fabric.n_links + 1
 
@@ -76,8 +73,7 @@ def compile_drain(sharding, fabric, *, pool_size: int = 65536,
         s((Lp,), jnp.int32),
     )
     return KOPS.drain_tick.lower(
-        *args, n_apps=N_APPS, n_routers=fabric.n_routers,
-        use_pallas=use_pallas, interpret=False).compile()
+        *args, n_apps=N_APPS, n_routers=fabric.n_routers).compile()
 
 
 def compile_run(eng, state, sharding):
@@ -148,7 +144,7 @@ def main(argv=None) -> int:
     chip = one_chip(topo)
     print(f"described {TOPOLOGY}: "
           f"{next(iter(chip.device_set)).device_kind}")
-    ok = _report("jnp drain tick, 2-D paper widths",
+    ok = _report("drain tick, 2-D paper widths",
                  lambda: compile_drain(chip, get_topology("2d", "paper")))
     sc = mix_scenario("workload1", topo="1d", scale="paper", tick_us=5.0,
                       horizon_ms=1.0)

@@ -49,17 +49,15 @@ from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 import jax
 import numpy as np
 
-from repro.kernels.ops import drain_widths
 from repro.netsim.engine import (
     engine_cache_stats,
     get_engine,
-    inject_candidates,
     member_mesh,
     member_state,
-    routed_candidates,
     shard_members,
     split_members,
     stack_members,
+    tick_counts,
 )
 from repro.obs import (
     Progress,
@@ -633,21 +631,6 @@ class RunCancelled(RuntimeError):
 # the executor: Plan nodes -> cells
 # ---------------------------------------------------------------------------
 
-def _engine_counts(state):
-    """Each member's ``ticks``, ``inject_ticks``, ``active_slot_ticks``,
-    drained slot-ticks (Σ over the drain's rungs of ticks x width) and
-    routed candidate-ticks (``engine.routed_candidates``), float64, flat
-    over any leading axes."""
-    m = state.metrics
-    ticks, inject, active, rungs = jax.device_get(
-        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.drain_rung_ticks))
-    widths = np.asarray(drain_widths(state.pool.active.shape[-1]), np.float64)
-    drained = np.asarray(rungs, np.float64).reshape(-1, widths.size) @ widths
-    return [np.asarray(x, np.float64).reshape(-1)
-            for x in (ticks, inject, active)] + [
-                drained, routed_candidates(state)]
-
-
 def _shape_of(x):
     """A run input's shape and dtype, with its sharding where that spans
     several devices: the member-sharded program is lowered for it."""
@@ -671,17 +654,17 @@ def _count_run(eng, final, *args, before=None):
     spec = jax.tree_util.tree_map(_shape_of, (final,) + args)
     leaves, tree = jax.tree_util.tree_flatten(spec)
     note_program((id(eng.run), tree, tuple(leaves)), eng.phase_map, *spec)
-    ticks, inject, active, drained, routed = _engine_counts(final)
+    c = tick_counts(final)
     if before is not None:
-        ticks, inject, active, drained, routed = (a - b for a, b in zip(
-            (ticks, inject, active, drained, routed), before))
-    chips = len(final.t.sharding.device_set) if ticks.size > 1 else 1
-    counter("engine.counters", iterations=ticks.max(),
-            chip_iterations=ticks.reshape(chips, -1).max(axis=1).tolist(),
-            member_ticks=ticks.sum(), inject_ticks=inject.sum(),
-            active_slot_ticks=active.sum(),
-            drain_slot_ticks=drained.sum(), inject_cand_ticks=routed.sum(),
-            inject_cand_width=inject_candidates(final))
+        c = c.since(before)
+    chips = len(final.t.sharding.device_set) if c.ticks.size > 1 else 1
+    counter("engine.counters", iterations=c.ticks.max(),
+            chip_iterations=c.ticks.reshape(chips, -1).max(axis=1).tolist(),
+            member_ticks=c.ticks.sum(), inject_ticks=c.inject_ticks.sum(),
+            active_slot_ticks=c.active_slot_ticks.sum(),
+            drain_slot_ticks=c.drain_slot_ticks.sum(),
+            inject_cand_ticks=c.inject_cand_ticks.sum(),
+            inject_cand_width=c.inject_cand_width)
 
 
 def _run_faulted(eng, inits, cells, host):
@@ -717,7 +700,7 @@ def _run_faulted(eng, inits, cells, host):
                 cur[i] += 1
             if cur[i] < len(tl):
                 t_stop[i] = tl[cur[i]][0]
-        before = _engine_counts(state) if tracing() else None
+        before = tick_counts(state) if tracing() else None
         state = jax.block_until_ready(eng.run_window(state, t_stop))
         _count_run(eng, state, t_stop, before=before)
     return [member_state(state, i) for i in range(len(cells))]

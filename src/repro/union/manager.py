@@ -16,12 +16,10 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
-import jax
 import numpy as np
 
 from repro.core import workloads as W
 from repro.core.translator import translate_source
-from repro.kernels.ops import drain_widths
 from repro.netsim import metrics as MET
 from repro.netsim.config import NetConfig
 from repro.netsim.engine import (
@@ -30,10 +28,9 @@ from repro.netsim.engine import (
     JobSpec,
     URSpec,
     get_engine,
-    inject_candidates,
     job_vm,
     member_state,
-    routed_candidates,
+    tick_counts,
 )
 from repro.netsim.placement import place_jobs
 from repro.netsim.topology import Fabric, get_topology
@@ -229,22 +226,18 @@ def member_report(state, rs: ResolvedScenario, wall_s: float = 0.0,
         ],
         envelope=dict(Jmax=cap.Jmax, Pmax=cap.Pmax, OPmax=cap.OPmax),
     )
-    m = state.metrics
-    ticks, inject_ticks, active, peak, rungs = jax.device_get(
-        (m.ticks, m.inject_ticks, m.active_slot_ticks, m.peak_active,
-         m.drain_rung_ticks))
-    ticks, slots = int(ticks), int(state.pool.active.shape[-1])
-    inject_ticks, cands = int(inject_ticks), inject_candidates(state)
+    c = tick_counts(state)
+    ticks, slots = int(c.ticks[0]), int(state.pool.active.shape[-1])
+    inject_ticks, cands = int(c.inject_ticks[0]), c.inject_cand_width
     rep["engine"] = dict(
         ticks=ticks, inject_ticks=inject_ticks,
-        mean_pool_occupancy=(float(active) / (ticks * slots)
+        mean_pool_occupancy=(float(c.active_slot_ticks[0]) / (ticks * slots)
                              if ticks else 0.0),
-        peak_pool_occupancy=int(peak) / slots,
-        mean_drain_width=(
-            int(np.dot(rungs, np.asarray(drain_widths(slots), np.int64)))
-            / (ticks * slots) if ticks else 0.0),
+        peak_pool_occupancy=int(state.metrics.peak_active) / slots,
+        mean_drain_width=(float(c.drain_slot_ticks[0]) / (ticks * slots)
+                          if ticks else 0.0),
         mean_inject_width=(
-            float(routed_candidates(state)[0]) / (inject_ticks * cands)
+            float(c.inject_cand_ticks[0]) / (inject_ticks * cands)
             if inject_ticks else 0.0),
     )
     if getattr(state, "probes", None) is not None:

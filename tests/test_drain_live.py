@@ -1,5 +1,5 @@
 """The drain over the live pool slots (``ops.drain_tick_live``) against
-the full-pool reference (``ref.drain_tick_ref``).
+the full-pool pass (``ops.drain_tick``).
 
 The live drain packs each member's live slots, in slot order, into the
 smallest rung of ``ops.drain_widths(M)`` that holds the fullest member,
@@ -7,11 +7,10 @@ drains them there and puts the per-slot results back. Every output must
 equal the full-pool pass bit for bit on CPU, whatever the live counts,
 wherever the live slots sit, whatever the inactive slots still hold.
 """
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops
 
 M, K, L, A, R = 4096, 6, 96, 3, 20
 WIDTHS = ops.drain_widths(M)
@@ -69,8 +68,8 @@ def test_live_drain_equals_full_pool(n, per_member_bw):
     counts = [n, n // 3]
     routes, rem, active, job, mina, t, ldr, rng = _pool(counts, seed=n)
     bw = _bandwidth(rng, len(counts), per_member_bw)
-    want = ref.drain_tick_ref(routes, rem, active, job, mina, t, DT, bw,
-                              ldr, A, R)
+    want = ops.drain_tick(routes, rem, active, job, mina, t, DT, bw, ldr,
+                          n_apps=A, n_routers=R)
     *got, rung = ops.drain_tick_live(routes, rem, active, job, mina, t, DT,
                                      bw, ldr, n_apps=A, n_routers=R)
     assert int(rung) == _rung(n)
@@ -93,8 +92,8 @@ def test_one_rung_pool(n):
     routes, rem, active, job, mina, t, ldr, rng = _pool(
         [n], seed=7, M=64, L=L)
     bw = _bandwidth(rng, 1, False)
-    want = ref.drain_tick_ref(routes, rem, active, job, mina, t, DT, bw,
-                              ldr, A, R)
+    want = ops.drain_tick(routes, rem, active, job, mina, t, DT, bw, ldr,
+                          n_apps=A, n_routers=R)
     *got, rung = ops.drain_tick_live(routes, rem, active, job, mina, t, DT,
                                      bw, ldr, n_apps=A, n_routers=R)
     assert int(rung) == 0
@@ -106,28 +105,9 @@ def test_live_slots_at_the_back_of_the_pool():
     routes, rem, active, job, mina, t, ldr, rng = _pool([0, 0], seed=3)
     active[0, -300:] = True
     bw = _bandwidth(rng, 2, True)
-    want = ref.drain_tick_ref(routes, rem, active, job, mina, t, DT, bw,
-                              ldr, A, R)
+    want = ops.drain_tick(routes, rem, active, job, mina, t, DT, bw, ldr,
+                          n_apps=A, n_routers=R)
     *got, rung = ops.drain_tick_live(routes, rem, active, job, mina, t, DT,
                                      bw, ldr, n_apps=A, n_routers=R)
     assert WIDTHS[int(rung)] == 512
     _assert_same(got, want)
-
-
-def test_live_drain_through_the_pallas_kernel():
-    """``use_pallas=True`` (interpret mode) at a packed width: the kernel
-    pads the rung to its block and agrees with the full-pool kernel."""
-    Mp = 1024
-    routes, rem, active, job, mina, t, ldr, rng = _pool(
-        [300, 40], seed=5, M=Mp, K=10)
-    bw = _bandwidth(rng, 2, True)
-    want = ops.drain_tick(routes, rem, active, job, mina, t, DT, bw, ldr,
-                          n_apps=A, n_routers=R, use_pallas=True)
-    *got, rung = ops.drain_tick_live(routes, rem, active, job, mina, t, DT,
-                                     bw, ldr, n_apps=A, n_routers=R,
-                                     use_pallas=True)
-    assert ops.drain_widths(Mp)[int(rung)] == 512
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g, np.float32),
-                                   np.asarray(w, np.float32), rtol=1e-6)
-    assert jnp.any(got[2]) or jnp.any(got[0] < rem)

@@ -10,6 +10,8 @@ host-plane tracing that reads them.
 * ``Metrics.inject_passes`` counts the passes each member's injecting
   ticks routed their candidates in, as a host replay of the fired
   counts does, and ``report["engine"]["mean_inject_width"]`` reads it;
+* ``report["engine"]`` and the ``engine.counters`` events are what
+  ``engine.tick_counts`` decodes from the final states;
 * ``Engine.phase_map`` names every ``tick/<phase>`` scope;
 * spans mirror onto a ``jax.profiler`` trace's host plane only while
   tracing, and the facade emits ``engine.counters`` only then.
@@ -24,11 +26,9 @@ import pytest
 import jax
 
 from repro import obs, union
-from repro.kernels.ops import drain_widths
 from repro.netsim import engine as ENG
 from repro.netsim.engine import (
-    MAXE, hlo_phases, inject_width, member_state, routed_candidates,
-    stack_members,
+    MAXE, hlo_phases, inject_width, member_state, stack_members, tick_counts,
 )
 from repro.obs import ProbeConfig
 from repro.union import manager as MGR
@@ -141,7 +141,7 @@ def test_mean_drain_width_equals_a_host_replay():
     eng = MGR.build(rs)
     state = stack_members([eng.init_state(seed=1),
                            eng.init_state(seed=2, start_us=[300.0])])
-    widths = drain_widths(1024)
+    widths = (1024, 512, 256)
     tick = jax.jit(eng.tick)
     s = jax.device_get(state)
     rungs = np.zeros((2, len(widths)), np.int64)
@@ -161,6 +161,8 @@ def test_mean_drain_width_equals_a_host_replay():
         s = nxt
     final = jax.device_get(eng.run(state))
     np.testing.assert_array_equal(final.metrics.drain_rung_ticks, rungs)
+    np.testing.assert_array_equal(tick_counts(final).drain_slot_ticks,
+                                  rungs @ np.asarray(widths))
     assert (rungs > 0).sum(axis=1).min() >= 2  # the width changes
     for b in range(2):
         blk = MGR.member_report(member_state(final, b), rs)["engine"]
@@ -222,7 +224,9 @@ def test_mean_inject_width_equals_a_host_replay(monkeypatch, width):
     np.testing.assert_array_equal(final.metrics.inject_passes, passes)
     assert len(taken) >= (2 if width == 1024 else 3)
     routed = passes * width
-    np.testing.assert_array_equal(routed_candidates(final), routed)
+    counts = tick_counts(final)
+    np.testing.assert_array_equal(counts.inject_cand_ticks, routed)
+    assert counts.inject_cand_width == n
     for b in range(2):
         blk = MGR.member_report(member_state(final, b), rs)["engine"]
         ticks = int(final.metrics.inject_ticks[b])
@@ -230,6 +234,53 @@ def test_mean_inject_width_equals_a_host_replay(monkeypatch, width):
         assert blk["mean_inject_width"] == pytest.approx(
             routed[b] / (ticks * n), rel=1e-12)
         assert 0.0 < blk["mean_inject_width"] < 1.0
+
+
+BLIP = dict(name="blip", events=[
+    dict(t_us=150.0, kind="random_links", fraction=0.15, seed=5,
+         factor=0.5),
+    dict(t_us=400.0, kind="random_links", fraction=0.15, seed=5,
+         factor=1.0),
+])
+
+
+@pytest.mark.parametrize("timed", [False, True])
+def test_tick_counts_decode_the_report_and_the_event(tracer, timed):
+    """One traced ``union.run`` of two members of the bursty mix, run
+    whole or, under a timed failure, in windows whose events count only
+    their own ticks: ``report["engine"]`` and the ``engine.counters``
+    events are what ``tick_counts`` decodes from the kept final states."""
+    grid = union.StudyGrid(failures=[BLIP]) if timed else union.StudyGrid()
+    tracer.enable()
+    res = union.run(union.Experiment(
+        name=f"tick-counts-{timed}", scenarios=[_bursty_scenario()],
+        members=2, base_seed=3, grid=grid), keep_states=True)
+    tracer.disable()
+    evs = [e["args"] for e in tracer.events if e["name"] == "engine.counters"]
+    assert len(evs) > 1 if timed else len(evs) == 1
+    counts = [tick_counts(st) for st in res.states]
+    M = 1024
+    for cell, c in zip(res.cells, counts):
+        assert cell.failure == ("blip" if timed else "healthy")
+        blk = cell.report["engine"]
+        ticks, inject = int(c.ticks[0]), int(c.inject_ticks[0])
+        assert (blk["ticks"], blk["inject_ticks"]) == (ticks, inject)
+        assert blk["mean_pool_occupancy"] == (
+            c.active_slot_ticks[0] / (ticks * M))
+        assert blk["mean_drain_width"] == c.drain_slot_ticks[0] / (ticks * M)
+        assert blk["mean_inject_width"] == (
+            c.inject_cand_ticks[0] / (inject * c.inject_cand_width))
+        # the drain took a narrower rung than the pool on some ticks
+        assert 0 < c.drain_slot_ticks[0] < ticks * M
+    for event, field in [("member_ticks", "ticks"),
+                         ("inject_ticks", "inject_ticks"),
+                         ("active_slot_ticks", "active_slot_ticks"),
+                         ("drain_slot_ticks", "drain_slot_ticks"),
+                         ("inject_cand_ticks", "inject_cand_ticks")]:
+        assert sum(e[event] for e in evs) == sum(
+            getattr(c, field)[0] for c in counts), event
+    assert {e["inject_cand_width"] for e in evs} == {
+        counts[0].inject_cand_width}
 
 
 def test_phase_map_names_every_phase():

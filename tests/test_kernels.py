@@ -1,60 +1,11 @@
-"""Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracles."""
+"""The SSD scan kernel: Pallas (interpret=True) vs its jnp oracle and the
+exact recurrence."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis", reason="hypothesis not installed")
-from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
-
-
-@pytest.mark.parametrize("M,L,K", [(512, 64, 10), (1024, 300, 10), (2048, 1500, 6)])
-@pytest.mark.parametrize("dt", [1.0, 5.0])
-def test_router_kernel_shapes(M, L, K, dt):
-    key = jax.random.PRNGKey(M + L)
-    routes = jax.random.randint(key, (M, K), -1, L)
-    rem = jax.random.uniform(jax.random.fold_in(key, 1), (M,)) * 1e5
-    act = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.5, (M,))
-    share = jax.random.uniform(jax.random.fold_in(key, 3), (L,)) * 1e3 + 1.0
-    a = ops.router_rate_drain(routes, rem, act, share, dt, use_pallas=False)
-    b = ops.router_rate_drain(routes, rem, act, share, dt, use_pallas=True)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(
-            np.asarray(x, np.float32), np.asarray(y, np.float32), rtol=1e-6
-        )
-
-
-@settings(max_examples=10, deadline=None)
-@given(
-    M=st.sampled_from([64, 257, 513]),
-    L=st.integers(8, 200),
-    frac=st.floats(0.0, 1.0),
-)
-def test_router_kernel_hypothesis(M, L, frac):
-    key = jax.random.PRNGKey(M * 31 + L)
-    routes = jax.random.randint(key, (M, 10), -1, L)
-    rem = jax.random.uniform(jax.random.fold_in(key, 1), (M,)) * 1e4
-    act = jax.random.bernoulli(jax.random.fold_in(key, 2), frac, (M,))
-    share = jax.random.uniform(jax.random.fold_in(key, 3), (L,)) * 100 + 0.5
-    a = ops.router_rate_drain(routes, rem, act, share, 2.0, use_pallas=False)
-    b = ops.router_rate_drain(routes, rem, act, share, 2.0, use_pallas=True)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(
-            np.asarray(x, np.float32), np.asarray(y, np.float32), rtol=1e-6
-        )
-
-
-def test_router_kernel_invariants():
-    """Fair share: a link shared by n messages gives each bw/n; a message's
-    rate is its bottleneck link's share."""
-    share = jnp.asarray([10.0, 2.0, 100.0])
-    routes = jnp.asarray([[0, 1, -1, -1], [0, 2, -1, -1]], jnp.int32)
-    rem = jnp.asarray([100.0, 100.0])
-    act = jnp.ones(2, bool)
-    new_rem, rate, _ = ops.router_rate_drain(routes, rem, act, share, 1.0)
-    assert float(rate[0]) == 2.0  # bottleneck link 1
-    assert float(rate[1]) == 10.0  # bottleneck link 0
+from repro.kernels.ssd_scan import ssd_scan
 
 
 @pytest.mark.parametrize("Q,hd,ds,nc,BH", [(8, 4, 4, 2, 2), (16, 8, 12, 3, 4),
@@ -66,8 +17,8 @@ def test_ssd_kernel_shapes(Q, hd, ds, nc, BH):
     A = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 2), (BH,)))
     Bm = jax.random.normal(jax.random.fold_in(key, 3), (BH, nc, Q, ds))
     Cm = jax.random.normal(jax.random.fold_in(key, 4), (BH, nc, Q, ds))
-    y1, h1 = ops.ssd_scan(x, dt, A, Bm, Cm, use_pallas=False)
-    y2, h2 = ops.ssd_scan(x, dt, A, Bm, Cm, use_pallas=True)
+    y1, h1 = ssd_scan(x, dt, A, Bm, Cm, use_pallas=False)
+    y2, h2 = ssd_scan(x, dt, A, Bm, Cm, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=3e-5, atol=3e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=3e-5, atol=3e-5)
 
@@ -81,7 +32,7 @@ def test_ssd_kernel_matches_recurrence():
     A = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 2), (BH,)))
     Bm = jax.random.normal(jax.random.fold_in(key, 3), (BH, nc, Q, ds))
     Cm = jax.random.normal(jax.random.fold_in(key, 4), (BH, nc, Q, ds))
-    y_k, _ = ops.ssd_scan(x, dt, A, Bm, Cm, use_pallas=True)
+    y_k, _ = ssd_scan(x, dt, A, Bm, Cm, use_pallas=True)
 
     # exact recurrence: h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T; y = C_t h_t
     def one(bh):

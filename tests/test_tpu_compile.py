@@ -59,15 +59,6 @@ def test_jnp_drain_compiles_at_2d_paper_widths(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()  # no Pallas kernel
 
 
-def test_pallas_drain_is_refused_by_mosaic(one_chip):
-    """The finding that keeps the engine on the jnp drain: Mosaic cannot
-    lower the kernel's in-kernel scatter-add (jax 0.9.0). When this
-    starts to compile, the kernel can be measured on the chip."""
-    with pytest.raises(NotImplementedError, match="scatter-add"):
-        rehearse.compile_drain(one_chip, get_topology("1d", "small"),
-                               pool_size=1024, use_pallas=True)
-
-
 def test_engine_run_compiles_for_workload1(one_chip, workload1_small):
     eng, state = workload1_small
     compiled = rehearse.compile_run(eng, state, one_chip)
