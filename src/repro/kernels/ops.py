@@ -10,6 +10,8 @@ the pool's live slots, in slot order, into the smallest width of a
 ladder (:func:`drain_widths`: M, M/2, ..., M/16) that holds every
 member's live slots, runs :func:`drain_tick` there, and puts the
 per-slot results back. The top rung, M, is the full-pool pass itself.
+Work on the delivered slots (the engine's latency pass) runs in the
+same branch, at the same width (``then``).
 The engine's injection packs the candidates that fired the same way
 (:func:`pack_columns`).
 """
@@ -115,9 +117,10 @@ def pack_columns(mask, col, W: int):
             .reshape(B, W))
 
 
-@functools.partial(jax.jit, static_argnames=("n_apps", "n_routers"))
+@functools.partial(jax.jit, static_argnames=("n_apps", "n_routers", "then"))
 def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
-                    bw_eff, link_dst_router, *, n_apps: int, n_routers: int):
+                    bw_eff, link_dst_router, *, n_apps: int, n_routers: int,
+                    then=None):
     """:func:`drain_tick` over the live pool slots only.
 
     Picks the smallest width ``W`` of ``drain_widths(M)`` that holds the
@@ -131,9 +134,22 @@ def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
     the full-pool pass (bit for bit where scatter-adds run in index
     order, as on CPU) for any pool whose ``bytes_rem`` are >= 0.
 
-    Returns ``drain_tick``'s five outputs and the rung taken, an int32
-    index into ``drain_widths(M)``. The top rung is ``drain_tick`` on
-    the whole pool, with nothing gathered or scattered around it.
+    ``then(take, delivered)``, where given, runs in the same branch on
+    the packed slots: ``take(x)`` gives a ``(B, M, ...)`` array at the
+    rung's ``(B, W)`` columns, and ``delivered`` is the drain's
+    ``(B, W)`` delivery mask there (padding columns not delivered). Work
+    that only reads the delivered slots thus runs at the rung's width,
+    on the same slots in the same order as over the whole pool.
+    ``then`` is static: a closure over the caller's traced values, traced
+    once inside the caller's jit. This function stays a call of its own
+    there: inlined into the engine's loop, the v5e compiler moved the
+    VMs' op tables out of VMEM and the emission's gathers from them
+    took ~0.4 ms more a tick (workload1, 1-D dragonfly at paper size).
+
+    Returns ``drain_tick``'s five outputs, ``then``'s result (None
+    without ``then``) and the rung taken, an int32 index into
+    ``drain_widths(M)``. The top rung is ``drain_tick`` and ``then`` on
+    the whole pool, with nothing gathered or scattered around them.
     """
     B, M, K = routes.shape
     widths = drain_widths(M)
@@ -141,6 +157,11 @@ def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
         drain_tick, t=t, dt=dt, bw_eff=bw_eff,
         link_dst_router=link_dst_router, n_apps=n_apps,
         n_routers=n_routers)
+    then = then or (lambda take, delivered: None)
+
+    def whole(_):
+        out = drain(routes, bytes_rem, active, job, min_arrive)
+        return out + (then(lambda x: x, out[2]),)
 
     def packed(W):
         # live slot m of member b goes to column cumsum(active)[b, m] - 1
@@ -166,11 +187,11 @@ def drain_tick_live(routes, bytes_rem, active, job, min_arrive, t, dt,
                     .reshape(B, M))
 
         return (put(bytes_rem, new_rem), put(jnp.zeros_like(bytes_rem), rate),
-                put(jnp.zeros_like(active), delivered), lb, rw)
+                put(jnp.zeros_like(active), delivered), lb, rw,
+                then(rows, delivered))
 
     n_live = jnp.max(jnp.sum(active, axis=1, dtype=jnp.int32))
     rung = jnp.sum(n_live <= jnp.asarray(widths[1:], jnp.int32),
                    dtype=jnp.int32)
-    branches = [lambda _: drain(routes, bytes_rem, active, job, min_arrive)]
-    branches += [(lambda _, W=W: packed(W)) for W in widths[1:]]
+    branches = [whole] + [(lambda _, W=W: packed(W)) for W in widths[1:]]
     return jax.lax.switch(rung, branches, None) + (rung,)

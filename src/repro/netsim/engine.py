@@ -14,7 +14,8 @@ One `tick` advances Δt of virtual time:
      slots packed into the smallest of M, M/2, ..., M/16 slots that holds
      them (`kernels.ops.drain_tick_live`).
   4. **Bookkeeping**: deliveries unblock VMs (cumulative counting — see
-     DESIGN §9 for the matching relaxation); latency histograms, per-app
+     DESIGN §9 for the matching relaxation); latency histograms (at the
+     drain's packed width, in its rung branch), per-app
      router-window counters (paper's 0.5 ms packet counters), link loads.
 
 **Stacked layout** (the one-engine-per-envelope design): all jobs' VM
@@ -552,6 +553,53 @@ def inject_fired(pool: PoolState, t, mask, W: int, cands: Callable,
     return pool, mask & (col < free_top), passes
 
 
+# ---------------------------------------------------------------------------
+# latency metrics of the delivered messages, at any width of pool slots
+# ---------------------------------------------------------------------------
+
+LAT_FIELDS = ("lat_hist", "lat_sum", "lat_cnt", "lat_min", "lat_max")
+
+
+def add_latencies(lat: Dict[str, jnp.ndarray], net: NetConfig, t, dt,
+                  delivered, live_m, app_of,
+                  inject_t) -> Dict[str, jnp.ndarray]:
+    """``Metrics``' five latency fields (``lat``, by their
+    :data:`LAT_FIELDS` names) after the slots ``delivered`` at the end
+    of tick ``t`` (``(B,)``) of ``dt`` us.
+
+    ``delivered``, ``app_of`` and ``inject_t`` are ``(B, W)`` slots of
+    any width: the drain's packed columns or the whole pool. A slot not
+    delivered, or of a member ``live_m`` freezes, adds 0 to the sums and
+    counts, +inf to the least and -inf to the largest, so the delivered
+    slots alone, in their order, decide the results.
+    """
+    bins_n = lat["lat_hist"].shape[-1]
+    delivered = delivered & live_m[:, None]
+    lat_us = (t[:, None] + dt) - inject_t  # delivered at tick end
+    bins = jnp.clip(
+        (jnp.log(jnp.maximum(lat_us / net.latency_hist_lo_us, 1e-6))
+         / math.log(net.latency_hist_ratio)),
+        0, bins_n - 1,
+    ).astype(jnp.int32)
+    d32 = delivered.astype(jnp.int32)
+    return dict(
+        lat_hist=_flat_add(
+            lat["lat_hist"],
+            jnp.where(delivered, app_of, 0) * bins_n
+            + jnp.where(delivered, bins, 0),
+            d32,
+        ),
+        lat_sum=_flat_add(
+            lat["lat_sum"], app_of, jnp.where(delivered, lat_us, 0.0)),
+        lat_cnt=_flat_add(lat["lat_cnt"], app_of, d32),
+        lat_min=_flat_min(
+            lat["lat_min"], app_of, jnp.where(delivered, lat_us, jnp.inf)),
+        lat_max=_flat_max(
+            lat["lat_max"], app_of,
+            jnp.where(delivered, lat_us, -jnp.inf)),
+    )
+
+
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(
     r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(([^)]*)\)")
@@ -571,16 +619,18 @@ _HLO_STRUCTURAL = {"parameter", "constant", "get-tuple-element", "tuple",
 def hlo_phases(hlo_text: str) -> Dict[str, str]:
     """``{instruction name: phase}`` of a compiled module's text.
 
-    An instruction's phase is the ``tick/<phase>`` scope of its
-    ``op_name`` metadata. A fusion takes its root instruction's scope;
-    where the compiler left the root without metadata (index arithmetic
-    it added), the fusion's own, else that of the scoped instruction
-    nearest the root. Data movement the compiler inserted with no
-    metadata (copies, prefetches, layout changes outside any fusion)
-    takes the phase of the first instruction it feeds, else of the one
-    that feeds it; so does an instruction of a while loop's body (or of
-    a branch it runs) whose metadata names no tick scope, as the SPMD
-    partitioner leaves what it rebuilds under a ``shard_map``.
+    An instruction's phase is the innermost ``tick/<phase>`` scope of
+    its ``op_name`` metadata (the latency pass runs inside the drain's
+    rung branch, under ``tick/drain``). A fusion takes its root
+    instruction's scope; where the compiler left the root without
+    metadata (index arithmetic it added), the fusion's own, else that of
+    the scoped instruction nearest the root. Data movement the compiler
+    inserted with no metadata (copies, prefetches, layout changes
+    outside any fusion) takes the phase of the first instruction it
+    feeds, else of the one that feeds it; so does an instruction of a
+    while loop's body (or of a branch it runs) whose metadata names no
+    tick scope, as the SPMD partitioner leaves what it rebuilds under a
+    ``shard_map``.
     """
     own: Dict[str, Optional[str]] = {}
     calls: Dict[str, str] = {}
@@ -609,10 +659,10 @@ def hlo_phases(hlo_text: str) -> Dict[str, str]:
         op = _HLO_OP_NAME.search(line)
         if op is None:
             bare.add(name)
-        ph = _TICK_PHASE.search(op.group(1)) if op else None
-        own[name] = ph.group(1) if ph else None
-        if ph is not None:
-            last[comp] = ph.group(1)
+        ph = _TICK_PHASE.findall(op.group(1)) if op else None
+        own[name] = ph[-1] if ph else None
+        if ph:
+            last[comp] = ph[-1]
         for sub in _HLO_SUBCOMPUTATION.findall(line):
             inner.add(sub)
             if m.group(3) == "fusion":
@@ -715,7 +765,8 @@ def build_engine(
     The drain tick (``kernels.ops.drain_tick``) runs each tick over the
     live pool slots at the smallest width of
     ``kernels.ops.drain_widths(M)`` that holds them (the top rung, ``M``,
-    is the full-pool pass).
+    is the full-pool pass); the latency metrics (:func:`add_latencies`)
+    run in the same rung branch, on the same packed slots.
 
     Injecting ticks route and allocate only the candidates that fired,
     the job candidates' then the UR source's, packed in emission order
@@ -1144,13 +1195,23 @@ def build_engine(
         # delivery, plus per-link byte counters (kernels.ops.drain_tick),
         # over the live slots only: packed into the smallest rung of
         # M, M/2, ..., M/16 that holds every member's live slots (the top
-        # rung is the full-pool pass), results put back per slot ---
+        # rung is the full-pool pass), results put back per slot. The
+        # latency metrics run in the same rung branch on the packed slots:
+        # a delivered slot is a live one ---
+        def latency_pass(take, d):
+            # the gathers of its inputs at the rung count as latency too
+            with jax.named_scope("tick/latency"):
+                return add_latencies(
+                    {f: getattr(metrics, f) for f in LAT_FIELDS}, net, t, dt,
+                    d, live_m, take(pool.job), take(pool.inject_t))
+
         with jax.named_scope("tick/drain"):
-            (new_rem, _rate, delivered, lb_delta, rw_delta,
+            (new_rem, _rate, delivered, lb_delta, rw_delta, lat_stats,
              drain_rung) = KOPS.drain_tick_live(
                 pool.routes, pool.bytes_rem, pool.active, pool.job,
                 pool.min_arrive, t, jnp.float32(dt), bw_run, link_dstr,
                 n_apps=n_apps, n_routers=R,
+                then=latency_pass,
             )
             # horizon-frozen members may still carry in-flight messages:
             # their drain results are discarded (the freeze in place of a
@@ -1160,35 +1221,11 @@ def build_engine(
             link_bytes = metrics.link_bytes + lb_delta * live_m[:, None]
             router_win = metrics.router_win + rw_delta * live_m[:, None, None]
 
-        # --- latency metrics ---
-        with jax.named_scope("tick/latency"):
-            lat = (t[:, None] + dt) - pool.inject_t  # delivered at tick end
-            ratio = math.log(net.latency_hist_ratio)
-            bins = jnp.clip(
-                (jnp.log(jnp.maximum(lat / net.latency_hist_lo_us, 1e-6))
-                 / ratio),
-                0, BINS - 1,
-            ).astype(jnp.int32)
-            app_of = pool.job
-            d32 = delivered.astype(jnp.int32)
-            lat_hist = _flat_add(
-                metrics.lat_hist,
-                jnp.where(delivered, app_of, 0) * BINS
-                + jnp.where(delivered, bins, 0),
-                d32,
-            )
-            lat_sum = _flat_add(
-                metrics.lat_sum, app_of, jnp.where(delivered, lat, 0.0))
-            lat_cnt = _flat_add(metrics.lat_cnt, app_of, d32)
-            lat_min = _flat_min(
-                metrics.lat_min, app_of, jnp.where(delivered, lat, jnp.inf))
-            lat_max = _flat_max(
-                metrics.lat_max, app_of, jnp.where(delivered, lat, -jnp.inf))
-
-            # full-fidelity (app, link-level) histograms (compiled in only
-            # when requested; ``delivered`` is already live_m-gated above)
-            hist_st = state.hist
-            if hist is not None:
+        # full-fidelity (app, link-level) histograms (compiled in only
+        # when requested), over the whole pool
+        hist_st = state.hist
+        if hist is not None:
+            with jax.named_scope("tick/latency"):
                 msg_lvl = jnp.max(
                     jnp.where(
                         pool.routes >= 0,
@@ -1198,7 +1235,8 @@ def build_engine(
                 )  # (B, M)
                 hist_st = update_hist(
                     hist_st, hist,
-                    lat=lat, delivered=delivered, app=app_of, level=msg_lvl,
+                    lat=(t[:, None] + dt) - pool.inject_t,
+                    delivered=delivered, app=pool.job, level=msg_lvl,
                 )
 
         # --- 4. delivery notifications -> VMs (UR id J is dropped) ---
@@ -1284,9 +1322,7 @@ def build_engine(
             win_idx = metrics.win_idx + rotate.astype(jnp.int32)
 
         metrics = metrics._replace(
-            lat_hist=lat_hist, lat_sum=lat_sum, lat_cnt=lat_cnt,
-            lat_min=lat_min, lat_max=lat_max,
-            link_bytes=link_bytes, router_win=router_win,
+            **lat_stats, link_bytes=link_bytes, router_win=router_win,
             router_wins=router_wins, win_idx=win_idx,
         )
 

@@ -12,10 +12,14 @@ host-plane tracing that reads them.
   counts does, and ``report["engine"]["mean_inject_width"]`` reads it;
 * ``report["engine"]`` and the ``engine.counters`` events are what
   ``engine.tick_counts`` decodes from the final states;
-* ``Engine.phase_map`` names every ``tick/<phase>`` scope;
+* ``Engine.phase_map`` names every ``tick/<phase>`` scope, and the
+  latency pass, which runs inside each of the drain's rung branches,
+  ``latency`` at the rung's width;
 * spans mirror onto a ``jax.profiler`` trace's host plane only while
   tracing, and the facade emits ``engine.counters`` only then.
 """
+import collections
+import dataclasses
 import glob
 import os
 import re
@@ -26,6 +30,7 @@ import pytest
 import jax
 
 from repro import obs, union
+from repro.kernels import ops as KOPS
 from repro.netsim import engine as ENG
 from repro.netsim.engine import (
     MAXE, hlo_phases, inject_width, member_state, stack_members, tick_counts,
@@ -299,6 +304,75 @@ def test_phase_map_names_every_phase():
             assert f"tick/{pm[m.group(1)]}" in op.group(1)
 
 
+@pytest.fixture(scope="module")
+def rung_program():
+    """The golden mix on a pool of 1,024 slots (rungs 1,024, 512 and
+    256), compiled for one member: its text and its phase map."""
+    sc = dataclasses.replace(EQ.mixed_scenario(), pool_size=1024)
+    eng = MGR.build(MGR.resolve(sc, seed=3))
+    text = eng._lower(eng.init_state(seed=1)).compile().as_text()
+    return text, hlo_phases(text)
+
+
+_INST = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+\[[\d,]*\])?"
+                   r".*?\s([\w\-]+)\(([^)]*)\)")
+
+
+@pytest.mark.parametrize("rung", range(3))
+def test_latency_scatters_map_to_latency(rung_program, rung):
+    """Each rung's branch of the drain runs the five latency scatters
+    (``lat_hist``, ``lat_sum``, ``lat_cnt``, ``lat_min``, ``lat_max``)
+    over the rung's width of slots. Traced under ``tick/latency`` inside
+    ``tick/drain``, they and the fusions that run them map to
+    ``latency``, none to ``drain``; the drain's own scatters stay
+    ``drain``."""
+    text, pm = rung_program
+    W = KOPS.drain_widths(1024)[rung]
+    branch = re.compile(
+        rf"/tick/drain/(?:jit\(drain_tick_live\)/)?cond/branch_{rung}_fun/")
+    shape, comp_of, fusion_of = {}, {}, {}
+    scatters = collections.defaultdict(list)  # latency? -> (name, updates)
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s\((.*)\)\s->", line)
+        if head is not None:
+            comp = head.group(1)
+            shape.update(re.findall(r"([\w.\-]+): (\w+\[[\d,]*\])",
+                                    head.group(2)))
+            continue
+        m = _INST.match(line)
+        if m is None:
+            continue
+        name, comp_of[name] = m.group(1), comp
+        shape[name] = m.group(2)
+        called = re.search(r"\bcalls=%?([\w.\-]+)", line)
+        if m.group(3) == "fusion" and called:
+            fusion_of[called.group(1)] = name
+        op = re.search(r'op_name="([^"]*)"', line)
+        at = branch.search(op.group(1)) if op else None
+        if m.group(3) == "scatter" and at:
+            updates = re.findall(r"%?([\w.\-]+)", m.group(4))[-1]
+            inner = op.group(1)[at.end():]
+            scatters[inner.startswith("tick/latency/")].append(
+                (name, updates))
+    lat = scatters[True]
+    assert len(lat) == 5
+    for name, updates in lat:
+        assert pm[name] == "latency"
+        if comp_of[name] in fusion_of:  # the op a profile sees
+            assert pm[fusion_of[comp_of[name]]] == "latency"
+        # one update a slot of the rung (B = 1)
+        assert np.prod([int(d) for d in re.findall(
+            r"\d+", shape[updates].split("[")[1])]) == W, shape[updates]
+    # the drain's three scatter-adds, and at a packed rung its packing and
+    # put-backs
+    assert len(scatters[False]) >= 3
+    assert {pm[name] for name, _ in scatters[False]} == {"drain"}
+    # nothing traced under tick/latency maps to the drain
+    assert not [n for n, p in pm.items() if p == "drain" and re.search(
+        rf'%{re.escape(n)} = .*op_name="[^"]*/tick/latency/', text)]
+
+
 def test_hlo_phases_rules():
     text = "\n".join([
         "%fused_computation.1 (p: f32[4]) -> f32[4] {",
@@ -313,6 +387,8 @@ def test_hlo_phases_rules():
         "calls=%fused_computation.1",
         '  %m = f32[4]{0} multiply(%fusion.7, %fusion.7), metadata={op_name='
         '"jit(f)/while/body/tick/emit/cond/branch_1_fun/mul"}',
+        '  %ls = f32[4]{0} add(%m, %m), metadata={op_name="jit(f)/while/'
+        'body/tick/drain/cond/branch_2_fun/tick/latency/add"}',
         '  ROOT %x = f32[4]{0} add(%m, %m), metadata={op_name="jit(f)/add"}',
         "}",
         "%loop_body (s: f32[4]) -> f32[4] {",
@@ -334,6 +410,8 @@ def test_hlo_phases_rules():
     # a copy the compiler added takes the phase of what it feeds
     assert pm["cp"] == "drain"
     assert pm["m"] == "emit"
+    # a scope nested in another: the innermost names the phase
+    assert pm["ls"] == "latency"
     assert "x" not in pm and "g" not in pm
     # an op of a while loop's body left with no tick scope (as the SPMD
     # partitioner leaves what it rebuilds) takes the phase of what it
